@@ -91,6 +91,8 @@ def main() -> None:
                          "CostTable artifact JSON")
     args = ap.parse_args()
 
+    from repro.exec.cache import enable_compile_cache
+    enable_compile_cache()
     from . import (table4_partition, fig5_redundancy, fig12_piece_vs_block,
                    fig13_throughput, table5_hetero, fig15_memory,
                    table67_optimal, fig_runtime_adapt, fig_exec_backend,

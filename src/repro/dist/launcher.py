@@ -15,7 +15,10 @@ plus its stage index and link roles, and rebuilds model/plan/params
 from it (:mod:`repro.dist.worker`).  ``DistSpec.workers`` picks the
 substrate — persistent threads (CI mode) or real OS processes via the
 multiprocessing *spawn* context — and ``DistSpec.transport`` the link
-kind; every combination moves the identical encoded bytes.
+kind; every combination moves the identical encoded bytes.  On a TPU
+host only threads can run, because the process that holds the chips is
+the only one that may use them: thread worker ``i`` runs its stage on
+local device ``i`` (mod the device count).
 
 Loss accounting mirrors the runtime's zero-dropped-in-flight
 guarantee: every submitted frame ends in ``report.outputs`` or in
@@ -127,6 +130,14 @@ class DistLauncher:
                  metrics=None, tracer=None):
         self.dep = deployment
         self.spec = spec or DistSpec()
+        if self.spec.workers == "process":
+            import jax
+            if jax.default_backend() == "tpu":
+                raise RuntimeError(
+                    "dist: workers='process' cannot run on a TPU host — "
+                    "this process already holds the chips and only one "
+                    "process may; use workers='thread', which places "
+                    "stage i on local device i")
         self.metrics = (metrics if metrics is not None
                         else getattr(deployment, "metrics", None)
                         or obs_metrics.default_registry())
@@ -625,8 +636,8 @@ class DistLauncher:
                   "dead": w.dead_reason}
             if w.stats is not None:
                 st.update({k: w.stats[k] for k in
-                           ("frames", "compute_s", "bytes_in", "bytes_out",
-                            "send_s") if k in w.stats})
+                           ("frames", "compute_s", "device_id", "bytes_in",
+                            "bytes_out", "send_s") if k in w.stats})
                 self._merge_spans(w, w.stats.get("spans") or [])
                 self.metrics.gauge("dist.worker.compute_s",
                                    worker=w.name).set(

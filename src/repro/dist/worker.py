@@ -100,7 +100,13 @@ class StageWorker:
             dep.model, st.nodes, list(st.fractions),
             name=f"stage{self.stage_index}", backend=spec.backend,
             mode=spec.mode)
-        self.params = dep.model.init(jax.random.PRNGKey(p["seed"]))
+        # stage i runs on local device i (mod the count): thread workers
+        # of one process spread over the host's chips; committed params
+        # and inputs make the stage's executable run there
+        devices = jax.local_devices()
+        self.device = devices[self.stage_index % len(devices)]
+        self.params = jax.device_put(
+            dep.model.init(jax.random.PRNGKey(p["seed"])), self.device)
         self.heartbeat_s = p["heartbeat_s"]
         self.epoch = p["epoch_wall"]
         self.trace = p["trace"]
@@ -127,15 +133,18 @@ class StageWorker:
                 self._frame(msg)
 
     def _frame(self, msg: Message) -> None:
+        import jax
+
         produced = {k: v for k, v in msg.tensors.items()
                     if k != "__image__"}
         image = msg.tensors.get("__image__")
         t_wall = time.time()
         t0 = time.perf_counter()
+        args = jax.device_put((produced, image), self.device)
         if len(msg.fids) > 1:
-            outs = self.executor.run_frames(self.params, produced, image)
+            outs = self.executor.run_frames(self.params, *args)
         else:
-            outs = self.executor(self.params, produced, image)
+            outs = self.executor(self.params, *args)
         outs = {k: np.asarray(v) for k, v in outs.items()}   # blocks
         dt = time.perf_counter() - t0
         if not msg.meta.get("warmup"):
@@ -187,7 +196,7 @@ class StageWorker:
     def _send_stats(self) -> None:
         self._send_ctrl(
             "stats", frames=self.frames, compute_s=self.compute_s,
-            bytes_in=self.upstream.bytes_recv,
+            device_id=self.device.id, bytes_in=self.upstream.bytes_recv,
             bytes_out=self.downstream.bytes_sent,
             send_s=self.downstream.send_s, spans=self.spans)
 

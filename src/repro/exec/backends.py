@@ -17,8 +17,9 @@ Registered backends:
     to the channel block) and carries the conv epilogue — bias, relu,
     optional non-overlapping max-pool — inside the kernel.
     ``interpret`` is auto-detected from the JAX platform: on TPU the
-    kernel actually compiles; elsewhere it runs in interpret mode
-    (slow but bit-faithful).  Channel block sizes come from
+    kernel actually compiles; on the CPU it runs in interpret mode
+    (slow but bit-faithful); any other platform raises.  Channel block
+    sizes come from
     ``exec.autotune``'s installed winners when present.
 
 A backend may additionally register a *fused* lowering: the signature
@@ -80,8 +81,15 @@ def has_fused(name: str | None) -> bool:
 
 
 def default_interpret() -> bool:
-    """Pallas interpret mode: only compile for real on TPU."""
-    return jax.default_backend() != "tpu"
+    """Pallas interpret mode: compiled on a TPU, interpreted on the CPU.
+
+    Any other platform raises: the kernel has no lowering there, and
+    interpreting it would hide the device the run was meant for."""
+    platform = jax.default_backend()
+    if platform not in ("cpu", "tpu"):
+        raise RuntimeError(f"the pallas conv backend runs compiled on tpu "
+                           f"or interpreted on cpu, not on {platform!r}")
+    return platform == "cpu"
 
 
 # ---------------------------------------------------------------------------

@@ -11,14 +11,22 @@ miss a ``compile`` span with its build wall-time) into the active
 tracer (:func:`repro.obs.trace.current`), and the hit/miss/eviction
 counters are published into the process-default metrics registry by a
 registered collector — hot paths only bump plain ints.
+
+Across processes, compiled XLA executables persist in JAX's own
+compilation cache; :func:`enable_compile_cache` points it at one fixed
+directory.
 """
 
 from __future__ import annotations
 
+import os
 import time as _time
 from collections import OrderedDict
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Mapping, Sequence
+
+import jax
 
 from .compiler import CompiledStage, segment_signature
 from ..obs import trace as obs_trace
@@ -62,6 +70,26 @@ def _publish_stats(reg) -> None:
 
 
 default_registry().register_collector(_publish_stats)
+
+
+#: JAX's persistent compilation cache when the environment names none:
+#: a fixed path (part of the cache key, so it must not move between
+#: runs), inside the checkout and git-ignored
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    A set ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads it itself and
+    this sets no other directory.  Otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`.  Entry points call this before their
+    first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 def cache_stats() -> CacheStats:

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import configs
-from ..compat import set_mesh
 from ..configs.shapes import SHAPES, input_specs, arch_for_shape
 from ..models.transformer import model as M
 from ..training.optim import AdamW
@@ -86,7 +85,7 @@ def build_lowered(arch_name: str, shape_name: str, multi_pod: bool,
     moe_pspec = P(daxes, None, None, None) if opts.get("moeshard") else None
     ring = ("model", mesh.shape["model"]) if opts.get("ring") else None
 
-    with mesh, set_mesh(mesh):
+    with mesh, jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = AdamW(lr=1e-4)
             opt_shape = jax.eval_shape(lambda: opt.init(params_shape))

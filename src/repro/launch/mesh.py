@@ -7,16 +7,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-portable ``jax.make_mesh`` with Auto axis types.
-
-    jax >= 0.6 takes ``axis_types``; older releases have neither the
-    kwarg nor ``jax.sharding.AxisType`` (Auto is the only behavior).
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types (GSPMD propagation)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

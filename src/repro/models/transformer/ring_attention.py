@@ -22,9 +22,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ...compat import shard_map
 
 
 def _block_update(carry, q_blk, k_blk, v_blk, q_pos, k_pos,

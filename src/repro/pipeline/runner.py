@@ -22,9 +22,8 @@ from typing import Callable, Mapping, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..compat import shard_map
 
 from ..core.pipeline_dp import PipelinePlan
 from .stage import StageExecutor, executors_from_plan

@@ -94,10 +94,14 @@ class WeightedArbiter:
     """Stride scheduler over a set of named tenants.
 
     Each tenant advances a virtual ``pass`` by ``1/weight`` per grant;
-    :meth:`pick` selects the eligible tenant with the lowest pass, so
-    grants converge to weight proportions and every eligible tenant with
-    positive weight is granted within a bounded interval (no
-    starvation).  Deterministic: ties break by registration order.
+    :meth:`pick` selects the eligible tenant whose next grant would end
+    first (lowest ``pass + 1/weight``), so grants converge to weight
+    proportions and every eligible tenant with positive weight is
+    granted within a bounded interval (no starvation).  Ordering by the
+    end and not the start of the next grant keeps every tenant within
+    one grant of its weight share from the first round: by start, a
+    heavy tenant waits behind one grant of every light one.
+    Deterministic: ties break by registration order.
     """
 
     def __init__(self, weights: dict[str, float] | None = None):
@@ -128,7 +132,8 @@ class WeightedArbiter:
                  if eligible is None or n in eligible]
         if not names:
             return None
-        name = min(names, key=lambda n: (self._pass[n], self._order[n]))
+        name = min(names, key=lambda n: (self._pass[n] + self._stride[n],
+                                         self._order[n]))
         self._pass[name] += self._stride[name]
         self.grants[name] = self.grants.get(name, 0) + 1
         return name

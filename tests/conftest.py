@@ -1,9 +1,17 @@
 """Shared fixtures.  NOTE: no XLA device-count flags here by design —
 smoke tests and benches must see the real (single) CPU device; only
 launch/dryrun.py forces 512 host devices (in its own process).
+
+The suite always runs on the CPU, also on a host with a TPU: a test
+process must never take a chip that only one process may hold, and
+child processes inherit the setting.
 """
 
-import jax
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+
+import jax  # noqa: E402
 import numpy as np
 import pytest
 

@@ -5,14 +5,12 @@ stitch must be lossless), property-tested over random CNN chains with
 hypothesis and over the real zoo DAGs.
 """
 
+import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-# property tests skip cleanly without hypothesis (requirements-dev.txt);
-# the plain zoo-model bit-exactness tests below always run
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
 
 from repro.core import make_pi_cluster, plan
 from repro.models.cnn import zoo

@@ -3,7 +3,9 @@ equivalence (property-style), and PlanSource provenance threading."""
 
 import dataclasses
 
+import hypothesis.strategies as st
 import pytest
+from hypothesis import given, settings
 
 from repro.api import FleetSpec, PlanSpec
 from repro.api import artifacts
@@ -15,8 +17,6 @@ from repro.fleet import (Autoscaler, FleetRouter, PlanRegistry, Tenant,
                          cluster_signature, fingerprint_model)
 from repro.models.cnn import zoo
 from repro.obs.metrics import MetricsRegistry
-
-from _hypothesis_compat import given, settings, st
 
 
 def _renamed(cluster, prefix):
@@ -77,8 +77,8 @@ def test_incremental_equals_scratch_under_churn(model_i, toggles):
 
 
 def test_incremental_equals_scratch_one_drop():
-    """Non-hypothesis twin of the property test (runs on minimal
-    installs): one drop on the heterogeneous 8-device cluster."""
+    """Fixed-input twin of the property test: one drop on the
+    heterogeneous 8-device cluster."""
     model = _MODELS[0]
     base = make_pi_cluster([1.5, 1.5, 1.2, 1.2, 1.0, 1.0, 0.8, 0.8])
     cache = PlannerCache()

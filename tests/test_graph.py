@@ -1,6 +1,7 @@
 """Unit + property tests for the graph IR and receptive-field math."""
 
-from _hypothesis_compat import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.core.graph import (Graph, LayerSpec, tile_widths,
                               proportional_widths)

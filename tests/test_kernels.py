@@ -102,6 +102,31 @@ def test_conv2d_fused_epilogue(shape, relu, pool):
                                **TOL[jnp.float32])
 
 
+@pytest.mark.parametrize("shape", [
+    (1, 300, 10, 4, 8, 3, 3, (1, 1), None),     # two bands, partial last
+    (2, 301, 11, 5, 6, 3, 3, (1, 1), (2, 2)),   # band rounded to pool
+    (1, 611, 19, 3, 8, 7, 7, (2, 2), None),     # 7x7/2 stem over bands
+    (1, 600, 20, 6, 4, 1, 1, (2, 2), (2, 2)),   # 1x1/2 projection + pool
+])
+def test_conv2d_row_bands(shape):
+    """Outputs taller than one band: every band reads its KH-1 row halo
+    and pools on the global pool grid (narrow widths keep it cheap)."""
+    from repro.kernels.conv2d.conv2d import _band_rows
+    n, h, w, ci, co, kh, kw, stride, pool = shape
+    ho, wo = (h - kh) // stride[0] + 1, (w - kw) // stride[1] + 1
+    assert ho > _band_rows(ho, wo, pool[0] if pool else 1)
+    x = jax.random.normal(jax.random.PRNGKey(0), (n, h, w, ci), jnp.float32)
+    wt = jax.random.normal(jax.random.PRNGKey(1), (kh, kw, ci, co),
+                           jnp.float32) / np.sqrt(kh * kw * ci)
+    b = jax.random.normal(jax.random.PRNGKey(2), (co,), jnp.float32)
+    out = conv2d_fused(x, wt, b, stride=stride, relu=True, pool=pool,
+                       interpret=True)
+    ref = conv2d_fused_ref(x, wt, b, stride=stride, relu=True, pool=pool)
+    assert out.shape == ref.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **TOL[jnp.float32])
+
+
 def test_conv2d_stride_normalization_and_validation():
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 8, 8, 4))
     wt = jax.random.normal(jax.random.PRNGKey(1), (3, 3, 4, 4)) * 0.1

@@ -13,6 +13,8 @@ ROOT = Path(__file__).resolve().parents[1]
 
 def run_py(code: str, devices: int = 8, timeout: int = 600):
     env = {
+        # never the accelerator: these children run on virtual CPU devices
+        "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
         "PYTHONPATH": str(ROOT / "src"),
         "PATH": "/usr/bin:/bin:/usr/local/bin",

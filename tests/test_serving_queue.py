@@ -1,15 +1,14 @@
 """Property-style coverage for request queueing, admission control,
 batching and tenant arbitration (serving.queueing).
 
-Uses the optional-hypothesis shim: with hypothesis installed the
-``@given`` properties fuzz the policies; without it they skip while the
-plain unit tests still run.
+The ``@given`` properties fuzz the policies with hypothesis.
 """
 
 from collections import deque
 from dataclasses import dataclass
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st  # noqa: F401
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.serving.queueing import (OpenLoopGenerator, TenantQueue,
                                     WeightedArbiter, coalesce)

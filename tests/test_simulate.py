@@ -1,7 +1,8 @@
 """Pipeline simulator invariants (paper Eq. 12 quantities), property-
 tested over random stage-time configurations."""
 
-from _hypothesis_compat import given, settings, st
+import hypothesis.strategies as st
+from hypothesis import given, settings
 
 from repro.core import make_pi_cluster, plan, simulate
 from repro.core.cost import SegmentCost, StageCost, Device
