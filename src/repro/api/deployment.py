@@ -25,6 +25,7 @@ from typing import Mapping, Sequence
 
 from ..core.cost import Cluster, CostTable
 from ..core.planner import PicoPlan, plan_with_spec
+from ..obs import compiles
 from ..obs import trace as obs_trace
 from ..obs.metrics import MetricsRegistry, default_registry
 from ..obs.trace import Tracer
@@ -49,6 +50,7 @@ def compile(model, cluster: Cluster,
     """
     plan_spec = plan_spec or PlanSpec()
     exec_spec = exec_spec or ExecSpec()
+    compiles.install()          # calibration compiles count too
     if params is None and key is not None:
         params = _init_params(model, key)
     # the deployment's tracer captures its whole lifecycle: the offline
@@ -135,6 +137,8 @@ class Deployment:
             self.tracer = Tracer()
         if self.metrics is None:
             self.metrics = MetricsRegistry()
+        self._calls = 0
+        compiles.install()
 
     # ---------------- plan views ----------------
 
@@ -204,18 +208,33 @@ class Deployment:
         the monolithic forward).  A single array returns one sink dict;
         a sequence returns a list of sink dicts.  Multi-frame sequences
         go through the compiled ``lax.scan`` ``run_frames`` path (one
-        dispatch per stage) unless ``exec_spec.scan_batch`` is off."""
+        dispatch per stage) unless ``exec_spec.scan_batch`` is off.
+
+        Each call records on :attr:`tracer` a ``run`` span (``call``,
+        ``frames``) holding one ``stage`` span per stage dispatch and,
+        on the scan path, ``run.stack`` (stacking the frames onto the
+        device) and ``run.split`` (slicing the sinks per frame); the
+        tracer is active for the call, so its compiles land there too."""
         if params is None:
             params = self.load_params().params
-        if hasattr(frames, "ndim"):
-            return self.runner(params, frames)
-        frames = list(frames)
-        if self.exec_spec.scan_batch and len(frames) > 1:
-            import jax.numpy as jnp
-            outs = self.runner.run_frames(params, jnp.stack(frames))
-            return [{k: v[i] for k, v in outs.items()}
-                    for i in range(len(frames))]
-        return [self.runner(params, x) for x in frames]
+        self._calls += 1
+        call = self._calls
+        tr = self.tracer
+        with obs_trace.scoped(tr), tr.wall_span("run", call=call) as span:
+            if hasattr(frames, "ndim"):
+                span.set(frames=1)
+                return self.runner(params, frames)
+            frames = list(frames)
+            span.set(frames=len(frames))
+            if self.exec_spec.scan_batch and len(frames) > 1:
+                import jax.numpy as jnp
+                with tr.wall_span("run.stack", call=call):
+                    stacked = jnp.stack(frames)
+                outs = self.runner.run_frames(params, stacked)
+                with tr.wall_span("run.split", call=call):
+                    return [{k: v[i] for k, v in outs.items()}
+                            for i in range(len(frames))]
+            return [self.runner(params, x) for x in frames]
 
     def simulate(self, frames: int = 64):
         """Closed-form steady-state report for the plan (Table 5

@@ -68,6 +68,9 @@ class _SpecBase:
     #: documents written before the field existed stay byte-identical,
     #: and so do every registry/artifact key derived from them.
     _omit_if_none: tuple = ()
+    #: fields a spec no longer has: older payloads that still carry
+    #: them load, and the value is dropped
+    _retired: tuple = ()
 
     def to_dict(self) -> dict:
         """Plain payload dict (raw float values — non-finite floats are
@@ -95,6 +98,8 @@ class _SpecBase:
         if version > SPEC_VERSION:
             raise ValueError(f"{cls.__name__} payload version {version} is "
                              f"newer than supported {SPEC_VERSION}")
+        for k in cls._retired:
+            d.pop(k, None)
         names = {f.name for f in dataclasses.fields(cls)}
         unknown = set(d) - names
         if unknown:
@@ -314,11 +319,9 @@ class ExecSpec(_SpecBase):
     executable cache (applied whenever a Deployment carrying the spec
     is built or loaded).  ``calibrate`` makes :func:`repro.api.compile`
     time each stage and re-plan on the measured
-    :class:`~repro.core.cost.CostTable`.  ``profile`` wraps every stage
-    invocation in a ``jax.profiler`` trace annotation so stages show up
-    named in XLA profiles (opt-in; no-op when the profiler is absent).
-    ``fuse`` lowers conv->pool chains as one fused kernel call on
-    backends with a fused lowering (numerics-neutral on the others).
+    :class:`~repro.core.cost.CostTable`.  ``fuse`` lowers conv->pool
+    chains as one fused kernel call on backends with a fused lowering
+    (numerics-neutral on the others).
     ``autotune`` makes :func:`repro.api.compile` search the Pallas
     kernel's channel block sizes per conv shape before calibration and
     persist the winners in the deployment's CostTable artifact.
@@ -331,10 +334,13 @@ class ExecSpec(_SpecBase):
     cache_size: int | None = None
     calibrate: bool = False
     calibrate_iters: int = 3
-    profile: bool = False       # jax.profiler bracket around each stage call
     fuse: bool = True           # fuse conv->pool chains into one kernel call
     autotune: bool = False      # tune kernel block sizes at compile time
     autotune_iters: int = 3
+
+    #: ``profile`` bracketed stage calls in a profiler annotation; every
+    #: stage call now records a ``stage`` span, which opens one
+    _retired = ("profile",)
 
     def __post_init__(self):
         if self.mode not in _EXEC_MODES:

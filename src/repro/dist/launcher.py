@@ -30,6 +30,13 @@ control-link EOF, or a worker-reported error) is surfaced as
 vocabulary the runtime's drain-and-repartition path reacts to — and
 the frames it stranded are reported dropped, ready for resubmission on
 a re-planned deployment (``dep.replan(cluster.restricted(alive))``).
+
+With ``DistSpec.trace`` on, the launcher records on its tracer (the
+deployment's, by default) per frame ``dist.submit`` (encode onto the
+feed link), ``dist.collect`` (take one sink message, decode it,
+resolve its fids) and ``frame`` (submit to resolve), all on the
+tracer's ``perf_counter`` timeline; the workers' spans join them on
+one track per worker when the workers report at shutdown.
 """
 
 from __future__ import annotations
@@ -47,10 +54,13 @@ import numpy as np
 
 from ..api.specs import DistSpec
 from ..obs import metrics as obs_metrics
-from ..obs.trace import Tracer
+from ..obs.trace import NULL_TRACER, Tracer
 from ..runtime.churn import DeviceLeave
-from .transport import (Message, TCPListener, TCPTransport, memory_pair)
+from .transport import Message, TCPListener, TCPTransport, memory_pair
 from .worker import StageWorker, build_payload, worker_main
+
+#: Perfetto row of the launcher's own spans.
+LAUNCHER_TRACK = "dist:launcher"
 
 
 @dataclass
@@ -143,6 +153,8 @@ class DistLauncher:
                         or obs_metrics.default_registry())
         self.tracer = (tracer if tracer is not None
                        else getattr(deployment, "tracer", None) or Tracer())
+        # where the per-frame spans go: nowhere unless DistSpec.trace
+        self._spans = self.tracer if self.spec.trace else NULL_TRACER
         self.stages = deployment.pico.pipeline.stages
         self.model = deployment.model
         self.churn_events: list[DeviceLeave] = []
@@ -157,7 +169,6 @@ class DistLauncher:
         self._stop_readers = False
         self._started = False
         self._closed = False
-        self._epoch = None
         self._t_start = None
         self._tmpdir = None
         self._next_fid = 0
@@ -205,7 +216,6 @@ class DistLauncher:
         if self._started:
             return self
         spec = self.spec
-        self._epoch = time.time()
         self._t_start = time.perf_counter()
         dep_json = self.dep.to_json()
         payloads = [
@@ -217,10 +227,11 @@ class DistLauncher:
                 last=(i == len(self.stages) - 1), seed=spec.seed,
                 heartbeat_s=spec.heartbeat_s,
                 start_timeout_s=spec.start_timeout_s,
-                chunk_bytes=spec.chunk_bytes, epoch_wall=self._epoch,
+                chunk_bytes=spec.chunk_bytes,
+                epoch=getattr(self.tracer, "epoch", self._t_start),
                 trace=spec.trace)
             for i, w in enumerate(self.workers)]
-        with self.tracer.wall_span("dist.launch", track="dist:launcher",
+        with self.tracer.wall_span("dist.launch", track=LAUNCHER_TRACK,
                                    workers=len(self.workers),
                                    mode=spec.workers,
                                    transport=spec.transport):
@@ -228,6 +239,8 @@ class DistLauncher:
                 self._start_processes(payloads)
             else:
                 self._start_threads(payloads)
+            for link in (self._feed, self._sink):
+                link.tracer, link.track = self._spans, LAUNCHER_TRACK
             self._started = True
             for w in self.workers:
                 self._spawn_reader(w)
@@ -405,11 +418,13 @@ class DistLauncher:
                 break                   # a worker died; run() will abort
         fid = self._next_fid
         self._next_fid += 1
-        arr = np.asarray(frame)
-        self._pending[fid] = arr
-        self._submit_ts[fid] = time.time()
-        self._submitted += 1
-        self._feed.send(Message("frame", [fid], {"__image__": arr}))
+        with self._spans.wall_span("dist.submit", track=LAUNCHER_TRACK,
+                                   fid=fid):
+            arr = np.asarray(frame)
+            self._pending[fid] = arr
+            self._submit_ts[fid] = time.perf_counter()
+            self._submitted += 1
+            self._feed.send(Message("frame", [fid], {"__image__": arr}))
         return fid
 
     def run(self, frames) -> DistReport:
@@ -433,13 +448,15 @@ class DistLauncher:
                 break
             fids = list(range(self._next_fid, self._next_fid + len(batch)))
             self._next_fid += len(batch)
-            now = time.time()
-            for fid, f in zip(fids, batch):
-                self._pending[fid] = f
-                self._submit_ts[fid] = now
-            self._submitted += len(batch)
-            arr = batch[0] if len(batch) == 1 else np.stack(batch)
-            self._feed.send(Message("frame", fids, {"__image__": arr}))
+            with self._spans.wall_span("dist.submit", track=LAUNCHER_TRACK,
+                                       fid=fids[0]):
+                now = time.perf_counter()
+                for fid, f in zip(fids, batch):
+                    self._pending[fid] = f
+                    self._submit_ts[fid] = now
+                self._submitted += len(batch)
+                arr = batch[0] if len(batch) == 1 else np.stack(batch)
+                self._feed.send(Message("frame", fids, {"__image__": arr}))
             i += len(batch)
         return self.shutdown()
 
@@ -452,16 +469,27 @@ class DistLauncher:
         if any(w.dead for w in self.workers):
             return False
         try:
-            msg = self._sink.recv(timeout=timeout)
+            msg = self._collect(timeout)
         except ConnectionError as e:
             last = self.workers[-1]
             self._mark_dead(last, f"sink link failed: {e}")
             return False
-        if msg is None:
-            return True
-        if msg.kind == "result" and not msg.meta.get("warmup"):
-            self._resolve(msg)
-        return msg.kind != "stop"
+        return msg is None or msg.kind != "stop"
+
+    def _collect(self, timeout: float) -> Message | None:
+        """Take one sink message (``None`` on timeout), decode it and
+        resolve its fids."""
+        body = self._sink.poll(timeout)
+        if body is None:
+            return None
+        with self._spans.wall_span("dist.collect",
+                                   track=LAUNCHER_TRACK) as span:
+            msg = self._sink.take(body)
+            if msg.fids:
+                span.set(fid=msg.fids[0])
+            if msg.kind == "result" and not msg.meta.get("warmup"):
+                self._resolve(msg)
+        return msg
 
     def _resolve(self, msg: Message) -> None:
         n = len(msg.fids)
@@ -472,10 +500,10 @@ class DistLauncher:
                                  for name, t in msg.tensors.items()}
             self._pending.pop(fid)
             t0 = self._submit_ts.pop(fid, None)
-            if t0 is not None and self.spec.trace:
-                now = time.time()
-                self.tracer.emit("frame", t0 - self._epoch, now - t0,
-                                 track="dist:launcher", fid=fid)
+            if t0 is not None and self._spans:
+                self._spans.emit("frame", t0 - self._spans.epoch,
+                                 time.perf_counter() - t0,
+                                 track=LAUNCHER_TRACK, fid=fid)
 
     def _drain_control(self, block_s: float = 0.0) -> None:
         deadline = time.monotonic() + block_s
@@ -518,12 +546,15 @@ class DistLauncher:
         if w.dead:
             return
         w.dead_reason = reason
-        t = time.time() - (self._epoch or time.time())
+        now = time.perf_counter()
+        t = now - (self._t_start or now)
         for dev in w.devices:
             self.churn_events.append(DeviceLeave(t, dev))
             self.metrics.counter("dist.churn.device_leave").inc()
-        self.tracer.instant("dist.churn", t, track="dist:launcher",
-                            worker=w.name, reason=reason)
+        self.tracer.instant("dist.churn",
+                            now - getattr(self.tracer, "epoch", now),
+                            track=LAUNCHER_TRACK, worker=w.name,
+                            reason=reason)
 
     def kill_worker(self, index: int) -> None:
         """Churn drill: make one worker crash *silently* (no stop, no
@@ -562,15 +593,11 @@ class DistLauncher:
                 if any(w.dead for w in self.workers):
                     break
                 try:
-                    msg = self._sink.recv(timeout=0.2)
+                    msg = self._collect(timeout=0.2)
                 except ConnectionError:
                     break
-                if msg is None:
-                    continue
-                if msg.kind == "stop":
+                if msg is not None and msg.kind == "stop":
                     draining = False    # every data message was ahead of it
-                elif msg.kind == "result" and not msg.meta.get("warmup"):
-                    self._resolve(msg)
             if draining and not any(w.dead for w in self.workers):
                 # deadline hit with frames still unresolved
                 for fid in sorted(self._pending):
@@ -578,8 +605,10 @@ class DistLauncher:
                         (fid, f"shutdown drain timed out after "
                               f"{self.spec.shutdown_timeout_s}s"))
                 self._pending.clear()
-            # stats messages trail the forwarded stop; give them a beat
-            stats_deadline = time.monotonic() + 2.0
+            # stats messages trail the forwarded stop, and carry each
+            # worker's span rings (up to RING_SPANS rows): give them
+            # the drain's budget
+            stats_deadline = time.monotonic() + self.spec.shutdown_timeout_s
             while (any(w.stats is None and not w.dead
                        for w in self.workers)
                    and time.monotonic() < stats_deadline):
@@ -638,7 +667,7 @@ class DistLauncher:
                 st.update({k: w.stats[k] for k in
                            ("frames", "compute_s", "device_id", "bytes_in",
                             "bytes_out", "send_s") if k in w.stats})
-                self._merge_spans(w, w.stats.get("spans") or [])
+                self._merge_spans(w, w.stats)
                 self.metrics.gauge("dist.worker.compute_s",
                                    worker=w.name).set(
                     st.get("compute_s", 0.0))
@@ -662,11 +691,15 @@ class DistLauncher:
             wall_s=wall, transport=self.spec.transport,
             workers_mode=self.spec.workers, n_stages=len(self.stages))
 
-    def _merge_spans(self, w: _Worker, spans: list) -> None:
-        """Re-emit worker-side spans on this launcher's tracer, one
-        track (= Perfetto process row) per real worker."""
-        if not self.spec.trace:
+    def _merge_spans(self, w: _Worker, stats: dict) -> None:
+        """Re-emit worker-side spans (already on this tracer's epoch) on
+        this launcher's tracer, one track (= Perfetto process row) per
+        real worker, and carry over what the worker's rings evicted."""
+        tr = self._spans
+        if not tr:
             return
-        for name, ts, dur, attrs in spans:
-            self.tracer.emit(name, ts, dur, track=f"dist:{w.name}",
-                             **{str(k): v for k, v in attrs.items()})
+        for name, ts, dur, attrs in stats.get("spans") or []:
+            tr.emit(name, ts, dur, track=f"dist:{w.name}",
+                    **{str(k): v for k, v in attrs.items()})
+        if stats.get("evicted"):
+            tr.note_evicted(stats["evicted"], stats["evicted_until"])

@@ -20,6 +20,15 @@ verb (``frame``/``result``/``stop``/``hello``/``ready``/``heartbeat``/
 ``stats``/``die``/``wire``), ``fids`` the frame ids a data message
 carries (len > 1 = micro-batch with a leading frame axis), ``tensors``
 named ndarrays, ``meta`` a JSON-safe dict.
+
+An endpoint whose owner hands it a tracer (``Transport.tracer``)
+records ``link.encode`` per send and ``link.decode`` per receive, and
+stamps each header with ``sent``, the sender's ``time.perf_counter()``
+taken as the header is written (after the tensor bytes are copied, so
+``link.wait`` overlaps ``link.encode`` only by the frame's final
+assembly).  The receiver records ``link.wait`` from that stamp to
+taking the message.  On Linux ``perf_counter`` is the system-wide
+monotonic clock, so the stamp holds across worker processes too.
 """
 
 from __future__ import annotations
@@ -34,6 +43,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..obs import metrics as obs_metrics
+from ..obs.trace import HOST_TRACK, NULL_TRACER
 
 MAGIC = b"PICO"
 _LEN = struct.Struct("<Q")
@@ -52,10 +62,16 @@ class Message:
     fids: list[int] = field(default_factory=list)
     tensors: dict[str, np.ndarray] = field(default_factory=dict)
     meta: dict = field(default_factory=dict)
+    sent: float | None = None       # sender's perf_counter stamp, if any
 
 
-def encode(msg: Message) -> bytes:
-    """Message -> one framed byte string (header JSON + tensor bytes)."""
+def _first(fids):
+    return fids[0] if fids else None
+
+
+def encode(msg: Message, stamp: bool = False) -> bytes:
+    """Message -> one framed byte string (header JSON + tensor bytes).
+    With ``stamp`` the header carries ``sent`` (see module docstring)."""
     specs, blobs = [], []
     for name, arr in msg.tensors.items():
         a = np.asarray(arr)
@@ -66,9 +82,11 @@ def encode(msg: Message) -> bytes:
         specs.append({"name": name, "dtype": str(a.dtype),
                       "shape": list(a.shape)})
         blobs.append(a.tobytes())
-    header = json.dumps({"kind": msg.kind, "fids": list(msg.fids),
-                         "meta": msg.meta, "tensors": specs},
-                        sort_keys=True).encode()
+    head = {"kind": msg.kind, "fids": list(msg.fids), "meta": msg.meta,
+            "tensors": specs}
+    if stamp:
+        head["sent"] = time.perf_counter()
+    header = json.dumps(head, sort_keys=True).encode()
     body = MAGIC + _HLEN.pack(len(header)) + header + b"".join(blobs)
     return _LEN.pack(len(body)) + body
 
@@ -93,13 +111,14 @@ def decode(body: bytes) -> Message:
         raise ValueError(f"frame length mismatch: consumed {off} of "
                          f"{len(body)} bytes")
     return Message(header["kind"], list(header["fids"]), tensors,
-                   header["meta"])
+                   header["meta"], header.get("sent"))
 
 
 class Transport:
     """One directed link endpoint.  Concrete transports implement
-    ``_send_bytes``/``_recv_bytes``; accounting and the codec are
-    shared here."""
+    ``_send_bytes``/``_recv_bytes``; accounting, the codec and the link
+    spans are shared here.  ``tracer``/``track`` say where this
+    endpoint's spans go (none by default)."""
 
     def __init__(self, link: str = "link", chunk_bytes: int = 1 << 20,
                  metrics=None):
@@ -112,11 +131,16 @@ class Transport:
         self.send_s = 0.0
         self._metrics = (metrics if metrics is not None
                          else obs_metrics.default_registry())
+        self.tracer = NULL_TRACER
+        self.track = HOST_TRACK
 
     # -- public API ------------------------------------------------------
     def send(self, msg: Message) -> int:
         """Encode and ship one message; returns bytes put on the wire."""
-        wire = encode(msg)
+        tr = self.tracer
+        with tr.wall_span("link.encode", track=self.track, link=self.link,
+                          fid=_first(msg.fids)):
+            wire = encode(msg, stamp=bool(tr))
         t0 = time.perf_counter()
         self._send_bytes(wire)
         dt = time.perf_counter() - t0
@@ -133,14 +157,33 @@ class Transport:
         """Next message, or ``None`` on timeout.  A timeout never
         corrupts framing: partially received frames are buffered and
         completed by the next call."""
-        body = self._recv_bytes(timeout)
-        if body is None:
-            return None
+        body = self.poll(timeout)
+        return None if body is None else self.take(body)
+
+    def poll(self, timeout: float | None = None) -> bytes | None:
+        """The next message's encoded body, or ``None`` on timeout (the
+        waiting half of :meth:`recv`)."""
+        return self._recv_bytes(timeout)
+
+    def take(self, body: bytes) -> Message:
+        """Account and decode a body :meth:`poll` returned (the taking
+        half of :meth:`recv`), recording ``link.wait`` and
+        ``link.decode`` where the endpoint has a tracer."""
         self.bytes_recv += len(body) + _LEN.size
         self.recvs += 1
         self._metrics.counter("dist.link.bytes_recv", link=self.link).inc(
             len(body) + _LEN.size)
-        return decode(body)
+        tr = self.tracer
+        if not tr:
+            return decode(body)
+        with tr.wall_span("link.decode", track=self.track,
+                          link=self.link) as span:
+            msg = decode(body)
+            span.set(fid=_first(msg.fids))
+        if msg.sent is not None:
+            tr.emit("link.wait", msg.sent - tr.epoch, span.t0 - msg.sent,
+                    track=self.track, link=self.link, fid=_first(msg.fids))
+        return msg
 
     def close(self) -> None:  # pragma: no cover - overridden
         pass

@@ -18,6 +18,14 @@ data messages (links are FIFO), which is what makes the launcher's
 drain lossless.  ``die`` simulates a crash: the worker exits silently
 — no stop forwarded, no stats, links left dangling — so peer-timeout
 detection can be drilled.
+
+With ``trace`` on, the worker records its spans into a
+:class:`~repro.obs.trace.Tracer` on the launcher tracer's ``epoch``
+(one ``perf_counter`` timeline): per frame ``stage.compute`` (the
+interval ``compute_s`` sums) holding ``worker.h2d``, the executor's
+``stage`` dispatch and ``worker.d2h``; then ``worker.send``; and the
+links' ``link.wait``/``link.decode``/``link.encode``.  Its bounded
+rings travel back in the ``stats`` message.
 """
 
 from __future__ import annotations
@@ -28,6 +36,7 @@ import traceback
 
 import numpy as np
 
+from ..obs.trace import NULL_TRACER, Tracer
 from .transport import Message, TCPListener, TCPTransport
 
 
@@ -36,7 +45,7 @@ def build_payload(deployment_json: str, stage: int, *, worker: str,
                   recv_image: bool, forward: list[str], forward_image: bool,
                   last: bool, seed: int, heartbeat_s: float,
                   start_timeout_s: float, chunk_bytes: int,
-                  epoch_wall: float, trace: bool) -> dict:
+                  epoch: float, trace: bool) -> dict:
     """The JSON-safe worker payload (see module docstring)."""
     return {"deployment": deployment_json, "stage": stage, "worker": worker,
             "devices": list(devices), "recv_nodes": list(recv_nodes),
@@ -45,7 +54,7 @@ def build_payload(deployment_json: str, stage: int, *, worker: str,
             "seed": int(seed), "heartbeat_s": float(heartbeat_s),
             "start_timeout_s": float(start_timeout_s),
             "chunk_bytes": int(chunk_bytes),
-            "epoch_wall": float(epoch_wall), "trace": bool(trace)}
+            "epoch": float(epoch), "trace": bool(trace)}
 
 
 class StageWorker:
@@ -63,7 +72,8 @@ class StageWorker:
         self.stage_index = payload["stage"]
         self.frames = 0
         self.compute_s = 0.0
-        self.spans: list[list] = []
+        self.tracer = (Tracer(epoch=payload["epoch"]) if payload["trace"]
+                       else NULL_TRACER)
         self._silent = False          # die received: simulate a crash
 
     # -- lifecycle -------------------------------------------------------
@@ -99,7 +109,8 @@ class StageWorker:
         self.executor = StageExecutor(
             dep.model, st.nodes, list(st.fractions),
             name=f"stage{self.stage_index}", backend=spec.backend,
-            mode=spec.mode)
+            mode=spec.mode, tracer=self.tracer)
+        self.upstream.tracer = self.downstream.tracer = self.tracer
         # stage i runs on local device i (mod the count): thread workers
         # of one process spread over the host's chips; committed params
         # and inputs make the stage's executable run there
@@ -108,8 +119,6 @@ class StageWorker:
         self.params = jax.device_put(
             dep.model.init(jax.random.PRNGKey(p["seed"])), self.device)
         self.heartbeat_s = p["heartbeat_s"]
-        self.epoch = p["epoch_wall"]
-        self.trace = p["trace"]
         self.forward = list(p["forward"])
         self.forward_image = p["forward_image"]
         self.last = p["last"]
@@ -135,36 +144,36 @@ class StageWorker:
     def _frame(self, msg: Message) -> None:
         import jax
 
+        tr = self.tracer
+        fid = msg.fids[0]
         produced = {k: v for k, v in msg.tensors.items()
                     if k != "__image__"}
         image = msg.tensors.get("__image__")
-        t_wall = time.time()
         t0 = time.perf_counter()
-        args = jax.device_put((produced, image), self.device)
-        if len(msg.fids) > 1:
-            outs = self.executor.run_frames(self.params, *args)
-        else:
-            outs = self.executor(self.params, *args)
-        outs = {k: np.asarray(v) for k, v in outs.items()}   # blocks
+        with tr.wall_span("stage.compute", stage=self.stage_index,
+                          worker=self.name, frames=len(msg.fids), fid=fid):
+            with tr.wall_span("worker.h2d", fid=fid):
+                args = jax.device_put((produced, image), self.device)
+            if len(msg.fids) > 1:
+                outs = self.executor.run_frames(self.params, *args)
+            else:
+                outs = self.executor(self.params, *args)
+            with tr.wall_span("worker.d2h", fid=fid):
+                outs = {k: np.asarray(v) for k, v in outs.items()}  # blocks
         dt = time.perf_counter() - t0
         if not msg.meta.get("warmup"):
             # the probe's wall is dominated by the stage compile — keep
             # it out of the steady-state compute stats validate() rates
             self.frames += len(msg.fids)
             self.compute_s += dt
-        if self.trace:
-            self.spans.append(["stage.compute", t_wall - self.epoch, dt,
-                               {"stage": self.stage_index,
-                                "worker": self.name,
-                                "frames": len(msg.fids),
-                                "fid": msg.fids[0]}])
-        avail = dict(produced)
-        avail.update(outs)
-        out = {n: avail[n] for n in self.forward}
-        if self.forward_image:
-            out["__image__"] = image
-        self.downstream.send(Message("result" if self.last else "frame",
-                                     msg.fids, out, msg.meta))
+        with tr.wall_span("worker.send", fid=fid):
+            avail = dict(produced)
+            avail.update(outs)
+            out = {n: avail[n] for n in self.forward}
+            if self.forward_image:
+                out["__image__"] = image
+            self.downstream.send(Message("result" if self.last else "frame",
+                                         msg.fids, out, msg.meta))
 
     # -- control ---------------------------------------------------------
     def _heartbeat(self) -> None:
@@ -194,11 +203,15 @@ class StageWorker:
             pass                    # launcher gone: nothing to tell
 
     def _send_stats(self) -> None:
+        tr = self.tracer
+        spans = tr.rows() if tr else []
+        evicted = tr.evicted if tr else 0
         self._send_ctrl(
             "stats", frames=self.frames, compute_s=self.compute_s,
             device_id=self.device.id, bytes_in=self.upstream.bytes_recv,
             bytes_out=self.downstream.bytes_sent,
-            send_s=self.downstream.send_s, spans=self.spans)
+            send_s=self.downstream.send_s, spans=spans, evicted=evicted,
+            evicted_until=tr.evicted_until if evicted else None)
 
     def _send_error(self, detail: str) -> None:
         self._send_ctrl("error", detail=detail, frames=self.frames)

@@ -6,11 +6,14 @@ stage structure, or a rebuilt but identical model, reuses the existing
 jitted executable instead of re-tracing.  Bounded LRU: past ``maxsize``
 the least-recently-used entry is dropped.
 
-Observability: every probe emits a ``cache.lookup`` instant (and every
-miss a ``compile`` span with its build wall-time) into the active
-tracer (:func:`repro.obs.trace.current`), and the hit/miss/eviction
-counters are published into the process-default metrics registry by a
-registered collector — hot paths only bump plain ints.
+Observability: every probe emits a ``cache.lookup`` instant into the
+active tracer (:func:`repro.obs.trace.current`), every miss observes
+the ``exec.compile.build_s`` histogram — the host time of building the
+stage's ``jax.jit`` wrappers, not an XLA compile: that happens at the
+executable's first call and is counted by :mod:`repro.obs.compiles` —
+and the hit/miss/eviction counters are published into the
+process-default metrics registry by a registered collector — hot paths
+only bump plain ints.
 
 Across processes, compiled XLA executables persist in JAX's own
 compilation cache; :func:`enable_compile_cache` points it at one fixed
@@ -136,8 +139,10 @@ def stage_cache_key(model, nodes, plans, needs, *, backend, relu, donate,
 def compiled_stage(model, nodes, plans, needs: Sequence, sinks: Sequence,
                    *, backend: str | None, relu: bool, donate: bool,
                    boundary: Mapping, static_key: tuple | None = None,
-                   fuse: bool = True) -> CompiledStage:
-    """Fetch-or-build the executable for one stage + boundary shapes."""
+                   fuse: bool = True, name: str = "stage") -> CompiledStage:
+    """Fetch-or-build the executable for one stage + boundary shapes.
+    ``name`` scopes the ops a miss lowers (``jax.named_scope``); it is
+    not part of the key, so identical stages share one executable."""
     key = stage_cache_key(model, nodes, plans, needs, backend=backend,
                           relu=relu, donate=donate, boundary=boundary,
                           static_key=static_key, fuse=fuse)
@@ -156,12 +161,9 @@ def compiled_stage(model, nodes, plans, needs: Sequence, sinks: Sequence,
                    hit=False)
     t0 = _time.perf_counter()
     cs = CompiledStage(model, nodes, plans, needs, sinks, backend=backend,
-                       relu=relu, donate=donate, fuse=fuse)
-    build_s = _time.perf_counter() - t0
-    default_registry().histogram("exec.compile.build_s").observe(build_s)
-    if tr:
-        tr.emit("compile", t0 - tr.epoch, build_s,
-                n_nodes=len(nodes), backend=backend or "default")
+                       relu=relu, donate=donate, fuse=fuse, name=name)
+    default_registry().histogram("exec.compile.build_s").observe(
+        _time.perf_counter() - t0)
     _CACHE[key] = cs
     while len(_CACHE) > _MAXSIZE:
         _CACHE.popitem(last=False)

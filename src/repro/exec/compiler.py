@@ -94,8 +94,12 @@ class CompiledStage:
                  needs: Sequence[tuple[str, str | None]],
                  sinks: Sequence[str], *, backend: str | None = None,
                  relu: bool = True, donate: bool = False,
-                 fuse: bool = True):
+                 fuse: bool = True, name: str = "stage"):
         self.model = model
+        # the traced body runs under jax.named_scope(name) (each layer
+        # under its node name, by run_segment), so device ops carry both
+        # in their op_name metadata; the computation is unchanged
+        self.name = name
         self.nodes = frozenset(nodes)
         self.plans = list(plans)
         self.needs = list(needs)
@@ -119,19 +123,20 @@ class CompiledStage:
     # traced bodies ------------------------------------------------------
 
     def _run(self, params, *bufs):
-        boundary = dict(zip(self.needs, bufs))
-        tiles_in = split_inputs(self.plans, self.needs, boundary)
-        tiles_out = []
-        for tp, tin in zip(self.plans, tiles_in):
-            if tp.empty:
-                tiles_out.append({})
-                continue
-            tiles_out.append(self.model.run_segment(
-                params, self.nodes, tin,
-                ranges=(tp.out_ranges, tp.in_ranges),
-                relu=self.relu, backend=self.backend,
-                fusion=self.fusion))
-        return stitch_outputs(self.plans, self.sinks, tiles_out)
+        with jax.named_scope(self.name):
+            boundary = dict(zip(self.needs, bufs))
+            tiles_in = split_inputs(self.plans, self.needs, boundary)
+            tiles_out = []
+            for tp, tin in zip(self.plans, tiles_in):
+                if tp.empty:
+                    tiles_out.append({})
+                    continue
+                tiles_out.append(self.model.run_segment(
+                    params, self.nodes, tin,
+                    ranges=(tp.out_ranges, tp.in_ranges),
+                    relu=self.relu, backend=self.backend,
+                    fusion=self.fusion))
+            return stitch_outputs(self.plans, self.sinks, tiles_out)
 
     def _run_frames(self, params, *bufs):
         def body(carry, xs):

@@ -116,6 +116,10 @@ class CNNDef:
         fused kernel call; a pair whose tile ranges do not line up on
         the pool grid silently executes unfused instead.
 
+        Each node runs under ``jax.named_scope(<node>)``, so the ops a
+        compile lowers name their layer (a fused pair under the conv's
+        name); the computation is unchanged.
+
         Returns {sink: tile covering ranges[0][sink] along W}.
         """
         backend = backend or self.backend
@@ -155,30 +159,32 @@ class CNNDef:
         for n in g.topo_order:
             if n not in nodes or n in vals:  # in vals: emitted by a fused conv
                 continue
-            spec = g.layers[n]
-            ps = g.preds[n]
-            if not ps:
-                xs = [inputs[(n, None)]]
-            else:
-                xs = [pred_slice(p, n) if p in nodes else inputs[(n, p)]
-                      for p in ps]
-            if spec.kind == "add":
-                vals[n] = sum(xs[1:], xs[0])
-                continue
-            if spec.kind == "concat":
-                vals[n] = jnp.concatenate(xs, axis=-1)
-                continue
-            full_in_w = (self.full_sizes[ps[0]] if ps else self.input_size)[0]
-            pad_w = g.tile_padding(n, req_out[n], full_in_w) \
-                if spec.kind in ("conv", "pool", "dwconv") else (0, 0)
-            if spec.kind == "conv" and n in fusion \
-                    and fused_ranges_ok(n, fusion[n]):
-                vals[fusion[n]] = apply_conv(
-                    spec, params.get(n), xs[0], relu, pad_w, backend=backend,
-                    pool_spec=g.layers[fusion[n]])
-                continue
-            vals[n] = apply_layer(spec, params.get(n), xs[0], relu, pad_w,
-                                  backend=backend)
+            with jax.named_scope(n):
+                spec = g.layers[n]
+                ps = g.preds[n]
+                if not ps:
+                    xs = [inputs[(n, None)]]
+                else:
+                    xs = [pred_slice(p, n) if p in nodes
+                          else inputs[(n, p)] for p in ps]
+                if spec.kind == "add":
+                    vals[n] = sum(xs[1:], xs[0])
+                    continue
+                if spec.kind == "concat":
+                    vals[n] = jnp.concatenate(xs, axis=-1)
+                    continue
+                full_in_w = (self.full_sizes[ps[0]] if ps
+                             else self.input_size)[0]
+                pad_w = g.tile_padding(n, req_out[n], full_in_w) \
+                    if spec.kind in ("conv", "pool", "dwconv") else (0, 0)
+                if spec.kind == "conv" and n in fusion \
+                        and fused_ranges_ok(n, fusion[n]):
+                    vals[fusion[n]] = apply_conv(
+                        spec, params.get(n), xs[0], relu, pad_w,
+                        backend=backend, pool_spec=g.layers[fusion[n]])
+                    continue
+                vals[n] = apply_layer(spec, params.get(n), xs[0], relu,
+                                      pad_w, backend=backend)
         return {s: vals[s] for s in g.sinks(nodes)}
 
     def forward(self, params, image: jax.Array, relu: bool = True,

@@ -11,22 +11,27 @@ real run can be diffed signal-for-signal.
   and the zero-alloc :data:`NULL_TRACER` default;
 * :mod:`~repro.obs.metrics` — :class:`MetricsRegistry` (counters,
   gauges, windowed histograms with nearest-rank p50/p95/p99) and the
-  versioned JSON snapshot codec shared with the bench gate.
+  versioned JSON snapshot codec shared with the bench gate;
+* :mod:`~repro.obs.compiles` — XLA compiles and compile-cache loads
+  counted from JAX's monitoring events (``xla.compiles``,
+  ``xla.compile_s``, ``compile`` spans).
 
 Summarize/validate traces from the shell with
 ``python -m repro.tools.trace``.
 """
 
-from .trace import (HOST_TRACK, NULL_TRACER, NullTracer, SPAN_NAMES, Span,
-                    Tracer, activate, current, from_chrome_trace, scoped,
-                    span_tree, validate_chrome_trace)
+from .trace import (HOST_TRACK, NULL_TRACER, NullTracer, REQUEST_SPANS,
+                    RING_SPANS, SPAN_NAMES, Span, Tracer, activate, current,
+                    from_chrome_trace, scoped, span_tree,
+                    validate_chrome_trace)
 from .metrics import (Counter, DEFAULT_WINDOW, Gauge, Histogram,
                       METRICS_SCHEMA_VERSION, MetricsRegistry, NULL_REGISTRY,
                       NullRegistry, default_registry, flatten, open_snapshot,
                       percentiles, quantile, registry_from_values)
 
 __all__ = [
-    "HOST_TRACK", "NULL_TRACER", "NullTracer", "SPAN_NAMES", "Span",
+    "HOST_TRACK", "NULL_TRACER", "NullTracer", "REQUEST_SPANS",
+    "RING_SPANS", "SPAN_NAMES", "Span",
     "Tracer", "activate", "current", "from_chrome_trace", "scoped",
     "span_tree", "validate_chrome_trace",
     "Counter", "DEFAULT_WINDOW", "Gauge", "Histogram",

@@ -11,11 +11,26 @@ modeled-vs-observed seconds, ...).
 
 Two tracer implementations share one interface:
 
-* :class:`Tracer` — records spans into a list and exports
-  Chrome-trace / Perfetto JSON (:meth:`Tracer.to_chrome_trace`);
+* :class:`Tracer` — records spans and exports Chrome-trace / Perfetto
+  JSON (:meth:`Tracer.to_chrome_trace`).  Per-request spans
+  (:data:`REQUEST_SPANS`) go into a ring of :data:`RING_SPANS` per
+  track, so a tracer that serves for hours stays bounded; lifecycle
+  spans (plan, compile, launch, churn, ...) are kept whole.  The
+  tracer counts what its rings evicted (:attr:`Tracer.evicted`,
+  :attr:`Tracer.evicted_until`) so a reader can tell an incomplete
+  window;
 * :class:`NullTracer` — the zero-allocation default: every method is a
   no-op returning cached singletons, so instrumented hot paths cost a
   single attribute lookup and call when tracing is off.
+
+Host spans have one primitive, :meth:`Tracer.wall_span`: it stamps the
+block with ``time.perf_counter()`` against the tracer's ``epoch`` and,
+while a JAX profiler session records, opens
+``jax.profiler.TraceAnnotation("repro.<name>")`` for the same extent,
+so the session holds the interval on the device trace's own clock.
+``emit`` records an interval whose ends were measured elsewhere
+(virtual time, or two stamps taken on different threads) and reaches
+no profiler.
 
 Instrumented library code reaches the active tracer through
 :func:`current`; an owner (a :class:`~repro.api.deployment.Deployment`,
@@ -25,12 +40,17 @@ the work it wants captured.
 
 from __future__ import annotations
 
+import heapq
+import itertools
 import json
 import math
 import time
+from collections import deque
 from contextlib import contextmanager
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Iterable, Mapping
+
+from jax.profiler import TraceAnnotation
 
 #: The shared span vocabulary.  Emitters are not restricted to it, but
 #: every subsystem's instrumentation sticks to these names so traces
@@ -44,7 +64,7 @@ SPAN_NAMES = (
     "plan",             # a full PICO optimization pass
     "replan",           # runtime churn/drift re-plan (incl. migration)
     "calibrate",        # one stage timed through its compiled executable
-    "compile",          # executable-cache miss: stage lowered + jitted
+    "compile",          # one XLA compile or compile-cache load (obs.compiles)
     "cache.lookup",     # executable-cache probe (hit or miss)
     "conv.fallback",    # Pallas conv fell back to the XLA reference
     "sched.admit",      # scheduler admission decision
@@ -56,7 +76,36 @@ SPAN_NAMES = (
     "fleet.autoscale",  # autoscaler watermark evaluation
     "dist.launch",      # dist worker spawn + handshake + warmup probe
     "dist.churn",       # dist worker declared dead (heartbeat/link/error)
+    "run",              # one Deployment.run call
+    "run.stack",        # Deployment.run: frames stacked onto the device
+    "run.split",        # Deployment.run: stacked sinks sliced per frame
+    "stage",            # one stage's dispatch (StageExecutor call)
+    "dist.submit",      # launcher: one frame encoded onto the feed link
+    "dist.collect",     # launcher: one sink message taken and resolved
+    "worker.h2d",       # dist worker: device_put of a frame's inputs
+    "worker.d2h",       # dist worker: wait for the device + copy back
+    "worker.send",      # dist worker: encode + send downstream
+    "link.wait",        # sender's stamp -> receiver takes the message
+    "link.encode",      # wire codec, one message
+    "link.decode",      # wire codec, one message
 )
+
+#: Spans recorded once per request (a call, a frame, a stage batch):
+#: they go into a bounded ring per track.  Every other name is a
+#: lifecycle span and is never evicted.
+REQUEST_SPANS = frozenset({
+    "frame", "frame.expired", "stage.compute", "stage.comm",
+    "halo.exchange", "cache.lookup", "conv.fallback", "sched.admit",
+    "sched.coalesce", "run", "run.stack", "run.split", "stage",
+    "dist.submit", "dist.collect", "worker.h2d", "worker.d2h",
+    "worker.send", "link.wait", "link.encode", "link.decode",
+})
+
+#: Capacity of each per-track ring of request spans.
+RING_SPANS = 65536
+
+#: Prefix of the profiler annotation a host span opens.
+ANNOTATION_PREFIX = "repro."
 
 #: Default track for host-side (wall-clock) spans.
 HOST_TRACK = "host"
@@ -106,6 +155,9 @@ class _NullSpanCtx:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        """Record nothing."""
+
 
 _NULL_CTX = _NullSpanCtx()
 
@@ -141,9 +193,13 @@ NULL_TRACER = NullTracer()
 
 
 class _WallSpanCtx:
-    """Context manager measuring a wall-clock span for a live Tracer."""
+    """Context manager measuring a host span for a live Tracer: a
+    ``perf_counter`` interval plus, while a profiler session records, a
+    profiler annotation of the same extent.  ``t0`` is the span's start
+    once entered; :meth:`set` adds attributes known only inside the
+    block."""
 
-    __slots__ = ("_tracer", "_name", "_track", "_attrs", "_t0")
+    __slots__ = ("_tracer", "_name", "_track", "_attrs", "_ann", "t0")
 
     def __init__(self, tracer, name, track, attrs):
         self._tracer = tracer
@@ -152,43 +208,118 @@ class _WallSpanCtx:
         self._attrs = attrs
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        # the annotation is made only while a profiler session records
+        if _profiling():
+            self._ann = TraceAnnotation(ANNOTATION_PREFIX + self._name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        self.t0 = _now()
         return self
 
+    def set(self, **attrs) -> None:
+        """Add attributes to the span before it closes."""
+        self._attrs.update(attrs)
+
     def __exit__(self, *exc):
-        t0 = self._t0
-        self._tracer.emit(self._name, t0 - self._tracer.epoch,
-                          time.perf_counter() - t0, track=self._track,
-                          **self._attrs)
+        t1 = _now()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        tr = self._tracer
+        tr._record(self._name, self.t0 - tr.epoch, t1 - self.t0,
+                   self._track, self._attrs)
         return False
+
+
+_now = time.perf_counter
+_profiling = TraceAnnotation.is_enabled
+
+
+def _seq_of(entry) -> int:
+    return entry[0]
+
+
+def _span(entry) -> Span:
+    _, name, ts, dur, track, attrs = entry
+    return Span(name, ts, dur, track, Span.freeze_attrs(attrs))
 
 
 class Tracer:
     """Span recorder with Chrome-trace / Perfetto JSON export.
 
-    Spans are appended in emission order; tracks (Perfetto process
-    rows) are created on first use in a stable order.  ``epoch`` anchors
-    wall-clock spans (:meth:`wall_span`) so their timestamps start near
-    zero like virtual-time spans do.
+    :attr:`spans` lists spans in emission order; tracks (Perfetto
+    process rows) are created on first use in a stable order.  ``epoch``
+    (a ``perf_counter`` value; pass another tracer's to share its
+    timeline) anchors wall-clock spans (:meth:`wall_span`) so their
+    timestamps start near zero like virtual-time spans do.
+
+    Request spans (:data:`REQUEST_SPANS`) live in one ring of
+    :data:`RING_SPANS` per track; ``evicted`` counts the spans the
+    rings dropped and ``evicted_until`` is the latest end among them
+    (``-inf`` while nothing was dropped).
     """
 
     enabled = True
 
-    def __init__(self):
-        self.spans: list[Span] = []
-        self.epoch = time.perf_counter()
+    def __init__(self, epoch: float | None = None):
+        self.epoch = _now() if epoch is None else float(epoch)
+        self.evicted = 0
+        self.evicted_until = -math.inf
+        self._seq = itertools.count()
+        self._lifecycle: list[tuple] = []
+        self._rings: dict[str, deque] = {}
 
     def __bool__(self) -> bool:
         return True
 
     def __len__(self) -> int:
-        return len(self.spans)
+        return len(self._lifecycle) + sum(map(len, self._rings.values()))
+
+    @property
+    def spans(self) -> list[Span]:
+        """Every span held, lifecycle and ring, in emission order."""
+        return [_span(e) for e in self._entries()]
+
+    def rows(self) -> list[list]:
+        """Every span as a plain ``[name, ts, dur, attrs]`` row, in
+        emission order: the cheap form a dist worker ships home."""
+        return [[name, ts, dur, attrs]
+                for _, name, ts, dur, _, attrs in self._entries()]
+
+    def _entries(self):
+        # copies taken under the GIL: a writer thread may keep appending
+        return heapq.merge(list(self._lifecycle),
+                           *[list(r) for r in list(self._rings.values())],
+                           key=_seq_of)
 
     def emit(self, name: str, ts: float, dur: float = 0.0,
              track: str = HOST_TRACK, **attrs) -> None:
         """Record one span at ``ts`` lasting ``dur`` seconds on ``track``."""
-        self.spans.append(Span(name, float(ts), float(dur), track,
-                               Span.freeze_attrs(attrs)))
+        self._record(name, float(ts), float(dur), track, attrs)
+
+    def _record(self, name, ts, dur, track, attrs) -> None:
+        # spans are held as plain tuples and become Span records only
+        # when read: the hot path pays for a tuple and an append
+        entry = (next(self._seq), name, ts, dur, track, attrs)
+        if name not in REQUEST_SPANS:
+            self._lifecycle.append(entry)
+            return
+        ring = self._rings.get(track)
+        if ring is None:
+            ring = self._rings[track] = deque(maxlen=RING_SPANS)
+        elif len(ring) == ring.maxlen:
+            _, _, old_ts, old_dur, _, _ = ring[0]
+            self.evicted += 1
+            if old_ts + old_dur > self.evicted_until:
+                self.evicted_until = old_ts + old_dur
+        ring.append(entry)
+
+    def note_evicted(self, count: int, until: float) -> None:
+        """Account for spans another tracer on this timeline evicted
+        before its spans were merged here."""
+        if count:
+            self.evicted += int(count)
+            self.evicted_until = max(self.evicted_until, float(until))
 
     def instant(self, name: str, ts: float, track: str = HOST_TRACK,
                 **attrs) -> None:
@@ -196,7 +327,8 @@ class Tracer:
         self.emit(name, ts, 0.0, track=track, **attrs)
 
     def wall_span(self, name: str, track: str = HOST_TRACK, **attrs):
-        """Context manager timing a host-side block with perf_counter."""
+        """Context manager timing a host-side block with perf_counter,
+        annotated ``repro.<name>`` for the JAX profiler."""
         return _WallSpanCtx(self, name, track, attrs)
 
     # ------------------------------------------------------------------
@@ -211,7 +343,7 @@ class Tracer:
         return list(seen)
 
     def by_name(self, name: str) -> list[Span]:
-        return [s for s in self.spans if s.name == name]
+        return [_span(e) for e in self._entries() if e[1] == name]
 
     # ------------------------------------------------------------------
     # Chrome trace / Perfetto export

@@ -7,6 +7,11 @@ lowering).  ``mode="eager"`` keeps the seed's per-tile Python loop as
 the bit-exactness oracle and for one-shot runs where compilation would
 not amortize.  ``run_frames`` micro-batches a stack of frames through
 ``lax.scan`` in one dispatch.
+
+Every call records a ``stage`` span (``stage`` attribute: the
+executor's name) around the stage's dispatch — into ``tracer`` when the
+owner hands one over (a dist worker), else into the active tracer —
+which also names the call in JAX profiler traces.
 """
 
 from __future__ import annotations
@@ -18,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.pipeline_dp import StagePlan
+from ..obs import trace as obs_trace
 from .halo import TilePlan, plan_tiles, split_inputs, stitch_outputs
 
 
@@ -33,10 +39,10 @@ class StageExecutor:
     mode: str = "compiled"           # "compiled" | "eager"
     donate: bool = False             # donate boundary buffers to XLA — only
     #                                  safe when the caller won't reuse them
-    profile: bool = False            # jax.profiler annotation per call
     fuse: bool = True                # lower conv->pool chains as one fused
     #                                  kernel call (compiled mode, backends
     #                                  with a fused lowering only)
+    tracer: object = None            # span sink; None -> the active tracer
 
     def __post_init__(self):
         g = self.model.graph
@@ -68,7 +74,7 @@ class StageExecutor:
     def __call__(self, params, produced: Mapping[str, jax.Array],
                  image: jax.Array | None = None) -> dict[str, jax.Array]:
         boundary = self.boundary_inputs(produced, image)
-        with self._profiler_bracket():
+        with self._span():
             if self.mode == "eager":
                 return self._run_eager(params, boundary)
             return self._executable(boundary)(params, boundary)
@@ -80,7 +86,7 @@ class StageExecutor:
         the same way.  Compiled mode scans the stack in one dispatch;
         eager mode loops frames through the oracle path and stacks."""
         boundary = self.boundary_inputs(produced, images)
-        with self._profiler_bracket():
+        with self._span():
             if self.mode == "eager":
                 n = next(iter(boundary.values())).shape[0]
                 per = [self._run_eager(params, {k: v[f] for k, v in
@@ -92,14 +98,9 @@ class StageExecutor:
 
     # ------------------------------------------------------------------
 
-    def _profiler_bracket(self):
-        """Opt-in ``jax.profiler`` named bracket (ExecSpec.profile) so
-        per-stage work shows up labelled in XLA device profiles; the
-        no-profile path costs one method call."""
-        if not self.profile:
-            from contextlib import nullcontext
-            return nullcontext()
-        return jax.profiler.TraceAnnotation(self.name)
+    def _span(self):
+        tr = self.tracer if self.tracer is not None else obs_trace.current()
+        return tr.wall_span("stage", stage=self.name)
 
     def _executable(self, boundary):
         from ..exec.cache import compiled_stage
@@ -107,7 +108,7 @@ class StageExecutor:
                               self.needs, self.sinks, backend=self.backend,
                               relu=True, donate=self.donate,
                               boundary=boundary, static_key=self._static_key,
-                              fuse=self.fuse)
+                              fuse=self.fuse, name=self.name)
 
     def _run_eager(self, params, boundary) -> dict[str, jax.Array]:
         """The seed path: eager Python loop over device tiles."""
@@ -134,13 +135,11 @@ def executors_from_plan(model: "CNNDef", stages: Sequence[StagePlan],  # noqa: F
     stages of one plan share boundary tensors, so donation here would
     let XLA clobber buffers a later stage still reads (single-stage
     callers opt in via the explicit ``donate=`` argument)."""
-    profile = False
     fuse = True
     if spec is not None:
         backend, mode = spec.backend, spec.mode
-        profile = getattr(spec, "profile", False)
         fuse = getattr(spec, "fuse", True)
     return [StageExecutor(model, st.nodes, list(st.fractions),
                           name=f"stage{si}", backend=backend, mode=mode,
-                          donate=donate, profile=profile, fuse=fuse)
+                          donate=donate, fuse=fuse)
             for si, st in enumerate(stages)]
