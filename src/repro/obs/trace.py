@@ -77,8 +77,8 @@ SPAN_NAMES = (
     "dist.launch",      # dist worker spawn + handshake + warmup probe
     "dist.churn",       # dist worker declared dead (heartbeat/link/error)
     "run",              # one Deployment.run call
-    "run.stack",        # Deployment.run: frames stacked onto the device
-    "run.split",        # Deployment.run: stacked sinks sliced per frame
+    "run.stack",        # Deployment.run: frames stacked onto the device (src)
+    "run.split",        # Deployment.run: stacked sinks unstacked per frame
     "stage",            # one stage's dispatch (StageExecutor call)
     "dist.submit",      # launcher: one frame encoded onto the feed link
     "dist.collect",     # launcher: one sink message taken and resolved

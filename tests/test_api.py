@@ -10,12 +10,14 @@ import json
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import repro
 from repro.api import (DeploySpec, ExecSpec, PlanSpec, artifacts,
                        reset_legacy_warnings)
+from repro.api.deployment import stack_frames
 from repro.core import (CostTable, make_pi_cluster, plan, replan, simulate)
 from repro.core.partition import PartitionResult
 from repro.models.cnn import zoo
@@ -336,6 +338,80 @@ def test_run_scan_batch_matches_per_frame():
         for k in a:
             np.testing.assert_allclose(np.asarray(a[k]), np.asarray(b[k]),
                                        rtol=1e-6, atol=1e-7)
+
+
+@pytest.fixture(scope="module")
+def scan_pair():
+    """A scanning deployment and its per-frame twin on the same weights."""
+    m = _tiny("squeezenet", size=48)
+    cluster = make_pi_cluster([1.5, 1.0])
+    dep = repro.compile(m, cluster).load_params()
+    looped = repro.compile(m, cluster,
+                           exec_spec=ExecSpec(scan_batch=False))
+    looped.params = dep.params
+    return dep, looped
+
+
+def _host_frames(n, dtype=np.float32, shape=(1, 48, 48, 3)):
+    rng = np.random.default_rng(7)
+    return [rng.standard_normal(shape).astype(dtype) for _ in range(n)]
+
+
+@pytest.mark.parametrize("kind", ["numpy", "jax"])
+def test_run_scan_frames_bit_exact_with_per_frame(scan_pair, kind):
+    dep, looped = scan_pair
+    host = _host_frames(3)
+    frames = host if kind == "numpy" else [jnp.asarray(x) for x in host]
+    outs = dep.run(frames)
+    plain = looped.run(host)
+    assert len(outs) == 3
+    for a, b in zip(outs, plain):
+        assert list(a) == list(b)
+        for k in a:
+            assert isinstance(a[k], jax.Array)
+            assert a[k].shape == b[k].shape and a[k].dtype == b[k].dtype
+            np.testing.assert_array_equal(np.asarray(a[k]),
+                                          np.asarray(b[k]))
+
+
+def test_run_float64_frames_canonicalised_as_jnp_stack(scan_pair):
+    dep, _ = scan_pair
+    f64 = _host_frames(3, np.float64)
+    assert jnp.stack(f64).dtype == jnp.float32
+    got = dep.run(f64)
+    want = dep.run([x.astype(np.float32) for x in f64])
+    for a, b in zip(got, want):
+        for k in a:
+            assert a[k].dtype == b[k].dtype == jnp.float32
+            np.testing.assert_array_equal(np.asarray(a[k]),
+                                          np.asarray(b[k]))
+
+
+_SMALL = (1, 2, 4, 3)
+
+
+@pytest.mark.parametrize("case, src", [
+    ("float32", "host"), ("float64", "host"), ("float16", "host"),
+    ("int64", "host"), ("uint8", "host"),
+    ("jax", "device"), ("mixed-types", "device"),
+    ("mixed-dtypes", "host")])
+def test_stack_frames_keeps_jnp_stack_dtype(case, src):
+    if case == "jax":
+        frames = [jnp.asarray(x) for x in _host_frames(3, shape=_SMALL)]
+    elif case == "mixed-types":
+        frames = _host_frames(3, shape=_SMALL)
+        frames[1] = jnp.asarray(frames[1])
+    elif case == "mixed-dtypes":
+        frames = _host_frames(3, shape=_SMALL)
+        frames[2] = frames[2].astype(np.float16)
+    else:
+        frames = [(np.arange(24).reshape(_SMALL) + i).astype(case)
+                  for i in range(3)]
+    got, how = stack_frames(frames)
+    want = jnp.stack(frames)
+    assert how == src and isinstance(got, jax.Array)
+    assert got.dtype == want.dtype and got.weak_type == want.weak_type
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------

@@ -4,9 +4,11 @@ annotations, and the XLA compile counter."""
 
 import glob
 import json
+import math
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -53,27 +55,33 @@ def _call_spans(dep, call):
 
 
 def test_deployment_run_records_one_span_tree(dep3):
-    frames = list(make_frames(dep3.model, 32))
-    dep3.run(frames)                                   # warm
-    call = dep3._calls + 1
-    outs = dep3.run(frames)
-    assert len(outs) == 32
-    run, kids = _call_spans(dep3, call)
-    assert run.attr("frames") == 32
-    names = sorted(s.name for s in kids)
-    n_stages = len(dep3.pico.pipeline.stages)
-    assert names == sorted(["run.stack", "run.split"] + ["stage"] * n_stages)
-    for s in kids:
-        assert _inside(s, run)
-        if s.name != "stage":
-            assert s.attr("call") == call
-    stack, = [s for s in kids if s.name == "run.stack"]
-    split, = [s for s in kids if s.name == "run.split"]
-    stages = sorted((s for s in kids if s.name == "stage"),
-                    key=lambda s: s.ts)
-    assert [s.attr("stage") for s in stages] == \
-        [f"stage{i}" for i in range(n_stages)]
-    assert stack.end <= stages[0].ts and stages[-1].end <= split.ts
+    host = list(make_frames(dep3.model, 32))
+    n_sinks = len(dep3.model.graph.sinks())
+    for frames, src in ((host, "host"),
+                        ([jnp.asarray(x) for x in host], "device")):
+        dep3.run(frames)                               # warm
+        call = dep3._calls + 1
+        outs = dep3.run(frames)
+        assert len(outs) == 32
+        run, kids = _call_spans(dep3, call)
+        assert run.attr("frames") == 32
+        names = sorted(s.name for s in kids)
+        n_stages = len(dep3.pico.pipeline.stages)
+        assert names == sorted(["run.stack", "run.split"]
+                               + ["stage"] * n_stages)
+        for s in kids:
+            assert _inside(s, run)
+            if s.name != "stage":
+                assert s.attr("call") == call
+        stack, = [s for s in kids if s.name == "run.stack"]
+        split, = [s for s in kids if s.name == "run.split"]
+        stages = sorted((s for s in kids if s.name == "stage"),
+                        key=lambda s: s.ts)
+        assert [s.attr("stage") for s in stages] == \
+            [f"stage{i}" for i in range(n_stages)]
+        assert stack.end <= stages[0].ts and stages[-1].end <= split.ts
+        assert stack.attr("src") == src
+        assert 1 <= split.attr("dispatches") <= n_sinks * math.ceil(32 / 100)
 
 
 def test_same_shape_run_adds_no_compile(dep3):
