@@ -1,7 +1,7 @@
 """Conv-kernel microbenchmark: tuned Pallas vs XLA ref vs pre-tuning tiles.
 
 For every *distinct* conv-epilogue shape in the zoo (channels, filter,
-stride, fused relu/pool — spatial sizes shrunk to smoke scale), times
+stride, fused activation/pool — spatial sizes shrunk to smoke scale), times
 three lowerings of the same fused chain:
 
 * ``tuned``  — the Pallas kernel at the autotuner's winning
@@ -107,7 +107,7 @@ def run(smoke: bool = False) -> list[str]:
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", RuntimeWarning)
         for d in shapes:
-            kw = dict(stride=d["stride"], relu=d["relu"], pool=d["pool"])
+            kw = dict(stride=d["stride"], act=d["act"], pool=d["pool"])
             res = autotune_conv(
                 d["x_shape"], d["w_shape"], iters=iters,
                 **(dict(candidates=candidates) if candidates else {}), **kw)

@@ -234,12 +234,16 @@ def cost_table_from_dict(d: Mapping) -> CostTable:
 # ---------------------------------------------------------------------------
 
 def layer_spec_to_dict(s: LayerSpec) -> dict:
-    return {"name": s.name, "kind": s.kind, "kernel": list(s.kernel),
-            "stride": list(s.stride), "padding": list(s.padding),
-            "in_channels": s.in_channels, "out_channels": s.out_channels,
-            "flops_coeff": s.flops_coeff, "param_bytes": s.param_bytes,
-            "global_rf": s.global_rf,
-            "tile_independent_flops": s.tile_independent_flops}
+    d = {"name": s.name, "kind": s.kind, "kernel": list(s.kernel),
+         "stride": list(s.stride), "padding": list(s.padding),
+         "in_channels": s.in_channels, "out_channels": s.out_channels,
+         "flops_coeff": s.flops_coeff, "param_bytes": s.param_bytes,
+         "global_rf": s.global_rf,
+         "tile_independent_flops": s.tile_independent_flops}
+    # additive, omitted at the default: ReLU graphs keep their bytes
+    if s.act != "relu":
+        d["act"] = s.act
+    return d
 
 
 def layer_spec_from_dict(d: Mapping) -> LayerSpec:
@@ -247,7 +251,7 @@ def layer_spec_from_dict(d: Mapping) -> LayerSpec:
                      tuple(d["stride"]), tuple(d["padding"]),
                      d["in_channels"], d["out_channels"], d["flops_coeff"],
                      d["param_bytes"], d["global_rf"],
-                     d["tile_independent_flops"])
+                     d["tile_independent_flops"], d.get("act", "relu"))
 
 
 def graph_to_dict(g: Graph) -> dict:

@@ -30,6 +30,10 @@ COMPUTE_KINDS = frozenset(
 CONNECTOR_KINDS = frozenset({"add", "concat", "input", "output", "identity"})
 # Kinds whose receptive field is the full input extent.
 GLOBAL_RF_KINDS = frozenset({"fc", "gpool", "attn"})
+# A conv's epilogue activation (``LayerSpec.act``): ReLU, leaky ReLU
+# with slope LEAKY_SLOPE (darknet's), or none.
+ACTIVATIONS = ("relu", "leaky", "linear")
+LEAKY_SLOPE = 0.1
 
 
 @dataclass(frozen=True)
@@ -41,6 +45,12 @@ class LayerSpec:
     output.  ``flops_coeff`` overrides the per-output-element FLOPs when
     the closed-form conv formula (Eq. 4) does not apply (attention, ssd,
     ffn, ...).  ``param_bytes`` is the weight memory of the layer.
+    ``act`` is the activation a conv applies after its bias (one of
+    :data:`ACTIVATIONS`); other kinds ignore it.
+
+    Kind ``reorg`` is space-to-depth with block ``kernel`` (YOLOv2's
+    passthrough): the geometry of a VALID ``kernel == stride`` window,
+    ``out_channels == kernel_w * kernel_h * in_channels``, no FLOPs.
     """
 
     name: str
@@ -57,10 +67,14 @@ class LayerSpec:
     # input must be fully gathered (true for attention: each query row is
     # computed once regardless of the tile layout).
     tile_independent_flops: bool = False
+    act: str = "relu"
 
     def __post_init__(self):
         if self.kind in GLOBAL_RF_KINDS and not self.global_rf:
             object.__setattr__(self, "global_rf", True)
+        if self.act not in ACTIVATIONS:
+            raise ValueError(f"{self.name}: act {self.act!r} is not one of "
+                             f"{ACTIVATIONS}")
 
     # ---- spatial maps -------------------------------------------------
     def out_size(self, in_size: tuple[int, int]) -> tuple[int, int]:

@@ -42,19 +42,25 @@ DEFAULT_CANDIDATES: tuple[tuple[int, int], ...] = (
     (128, 128), (128, 64), (64, 128), (64, 64), (32, 32), (16, 16), (8, 8))
 
 
-def shape_key(x_shape, w_shape, stride, relu=False, pool=None,
+#: the activation's part of a key; ``r1`` (ReLU) and ``r0`` (none) are
+#: the tags stored CostTables already hold, so their entries stay valid
+_ACT_TAGS = {"relu": "r1", "linear": "r0", "leaky": "rleaky"}
+
+
+def shape_key(x_shape, w_shape, stride, act="linear", pool=None,
               backend: str = "pallas") -> str:
     """Stable CostTable key for one conv-epilogue configuration.
 
     Spatial dims are excluded on purpose (see module docstring); the
-    key captures channels, filter, stride, epilogue, and backend.
+    key captures channels, filter, stride, epilogue (activation
+    ``act``, pool), and backend.
     """
     ci = x_shape[-1]
     kh, kw, _, co = w_shape
     sh, sw = stride
     p = "-" if pool is None else f"{pool[0]}x{pool[1]}"
     return (f"conv:{backend}:c{ci}x{co}:k{kh}x{kw}:s{sh}x{sw}"
-            f":r{int(bool(relu))}:p{p}")
+            f":{_ACT_TAGS[act]}:p{p}")
 
 
 # ---------------------------------------------------------------------------
@@ -81,11 +87,11 @@ def clear_installed() -> None:
     _TUNED.clear()
 
 
-def tuned_blocks(x_shape, w_shape, stride, relu=False, pool=None, *,
+def tuned_blocks(x_shape, w_shape, stride, act="linear", pool=None, *,
                  backend: str = "pallas") -> tuple[int | None, int | None]:
     """(block_ci, block_co) for this conv call, or (None, None) when no
     tuned entry is installed (the kernel default applies)."""
-    e = _TUNED.get(shape_key(x_shape, w_shape, stride, relu, pool, backend))
+    e = _TUNED.get(shape_key(x_shape, w_shape, stride, act, pool, backend))
     if e is None:
         return (None, None)
     return (int(e["block_ci"]), int(e["block_co"]))
@@ -119,7 +125,7 @@ def _time_call(fn, *args, iters: int) -> float:
 
 
 def autotune_conv(x_shape: Sequence[int], w_shape: Sequence[int], *,
-                  stride=(1, 1), relu: bool = False,
+                  stride=(1, 1), act: str = "linear",
                   pool: tuple[int, int] | None = None, bias: bool = True,
                   backend: str = "pallas",
                   candidates: Iterable[tuple[int, int]] = DEFAULT_CANDIDATES,
@@ -138,7 +144,7 @@ def autotune_conv(x_shape: Sequence[int], w_shape: Sequence[int], *,
     w = jax.random.normal(k2, tuple(w_shape), jnp.float32) * 0.1
     b = jax.random.normal(k3, (w_shape[-1],), jnp.float32) if bias else None
     stride = tuple(int(s) for s in stride)
-    skey = shape_key(x_shape, w_shape, stride, relu, pool, backend)
+    skey = shape_key(x_shape, w_shape, stride, act, pool, backend)
     reg = default_registry()
     tr = obs_trace.current()
     trials: list[tuple[int, int, float]] = []
@@ -146,7 +152,7 @@ def autotune_conv(x_shape: Sequence[int], w_shape: Sequence[int], *,
         for bci, bco in candidates:
             dt = _time_call(
                 lambda xx, ww: conv2d_fused(
-                    xx, ww, b, stride=stride, relu=relu, pool=pool,
+                    xx, ww, b, stride=stride, act=act, pool=pool,
                     block_ci=bci, block_co=bco, interpret=interpret),
                 x, w, iters=iters)
             trials.append((bci, bco, dt))
@@ -186,8 +192,9 @@ def conv_shapes(model) -> list[dict]:
             pspec = g.layers[fusion[n]]
             pool = (pspec.kernel[1], pspec.kernel[0])
         d = dict(x_shape=x_shape, w_shape=w_shape, stride=stride,
-                 relu=True, pool=pool)
-        shapes.setdefault(shape_key(x_shape, w_shape, stride, True, pool), d)
+                 act=spec.act, pool=pool)
+        shapes.setdefault(
+            shape_key(x_shape, w_shape, stride, spec.act, pool), d)
     return list(shapes.values())
 
 
@@ -207,11 +214,11 @@ def autotune_model(model, *, backend: str = "pallas",
     results: list[TuneResult] = []
     for d in conv_shapes(model):
         skey = shape_key(d["x_shape"], d["w_shape"], d["stride"],
-                         d["relu"], d["pool"], backend)
+                         d["act"], d["pool"], backend)
         if skey in table.kernels:
             continue
         res = autotune_conv(d["x_shape"], d["w_shape"], stride=d["stride"],
-                            relu=d["relu"], pool=d["pool"], backend=backend,
+                            act=d["act"], pool=d["pool"], backend=backend,
                             candidates=candidates, iters=iters, key=key)
         table.kernels[skey] = res.entry(backend)
         results.append(res)

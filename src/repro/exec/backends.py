@@ -14,8 +14,9 @@ Registered backends:
 ``pallas``
     The repro's implicit-GEMM Pallas kernel (``kernels.conv2d``), which
     handles any stride >= 1 and any channel count (tails are padded up
-    to the channel block) and carries the conv epilogue — bias, relu,
-    optional non-overlapping max-pool — inside the kernel.
+    to the channel block) and carries the conv epilogue — bias, the
+    layer's activation, optional non-overlapping max-pool — inside the
+    kernel.
     ``interpret`` is auto-detected from the JAX platform: on TPU the
     kernel actually compiles; on the CPU it runs in interpret mode
     (slow but bit-faithful); any other platform raises.  Channel block
@@ -23,7 +24,7 @@ Registered backends:
     ``exec.autotune``'s installed winners when present.
 
 A backend may additionally register a *fused* lowering: the signature
-covers the whole conv epilogue (conv + bias + relu + optional pool) in
+covers the whole conv epilogue (conv + bias + activation + optional pool) in
 one call, and ``exec.compiler.fusable_chains`` only rewrites segments
 for backends that have one — backends without it (xla) keep the exact
 composed-op sequence, preserving bit-equality with the eager oracle.
@@ -37,15 +38,16 @@ import jax
 import jax.numpy as jnp
 
 from ..core.graph import LayerSpec
+from ..kernels.conv2d.ref import activation
 
 # conv backend signature: (spec, params, x, pad_w) -> y  (NHWC, VALID +
 # explicit pad_w/ph padding, no bias, no activation)
 ConvFn = Callable[[LayerSpec, dict, jax.Array, tuple[int, int]], jax.Array]
 
-# fused lowering: (conv_spec, pool_spec | None, params, x, pad_w, relu)
-# -> y, with bias + relu (+ pool) applied — one kernel call per chain
+# fused lowering: (conv_spec, pool_spec | None, params, x, pad_w) -> y,
+# with bias + conv_spec.act (+ pool) applied — one kernel call per chain
 FusedConvFn = Callable[
-    [LayerSpec, Optional[LayerSpec], dict, jax.Array, tuple[int, int], bool],
+    [LayerSpec, Optional[LayerSpec], dict, jax.Array, tuple[int, int]],
     jax.Array]
 
 _REGISTRY: dict[str, ConvFn] = {}
@@ -107,10 +109,10 @@ def _conv_xla(spec: LayerSpec, p: dict, x: jax.Array,
     )
 
 
-def _tuned(xp: jax.Array, w: jax.Array, stride, relu: bool,
+def _tuned(xp: jax.Array, w: jax.Array, stride, act: str,
            pool) -> tuple[int | None, int | None]:
     from .autotune import tuned_blocks
-    return tuned_blocks(xp.shape, w.shape, stride, relu, pool,
+    return tuned_blocks(xp.shape, w.shape, stride, act, pool,
                         backend="pallas")
 
 
@@ -120,22 +122,21 @@ def _conv_pallas(spec: LayerSpec, p: dict, x: jax.Array,
     ph = spec.padding[1]
     xp = jnp.pad(x, ((0, 0), (ph, ph), pad_w, (0, 0)))
     stride = (spec.stride[1], spec.stride[0])
-    bci, bco = _tuned(xp, p["w"], stride, False, None)
+    bci, bco = _tuned(xp, p["w"], stride, "linear", None)
     return conv2d_kernel(xp, p["w"], stride=stride, block_ci=bci,
                          block_co=bco, interpret=default_interpret())
 
 
 def _conv_pallas_fused(spec: LayerSpec, pool_spec: LayerSpec | None, p: dict,
-                       x: jax.Array, pad_w: tuple[int, int],
-                       relu: bool) -> jax.Array:
+                       x: jax.Array, pad_w: tuple[int, int]) -> jax.Array:
     from ..kernels.conv2d.ops import conv2d_fused
     ph = spec.padding[1]
     xp = jnp.pad(x, ((0, 0), (ph, ph), pad_w, (0, 0)))
     stride = (spec.stride[1], spec.stride[0])
     pool = None if pool_spec is None \
         else (pool_spec.kernel[1], pool_spec.kernel[0])
-    bci, bco = _tuned(xp, p["w"], stride, relu, pool)
-    return conv2d_fused(xp, p["w"], p["b"], stride=stride, relu=relu,
+    bci, bco = _tuned(xp, p["w"], stride, spec.act, pool)
+    return conv2d_fused(xp, p["w"], p["b"], stride=stride, act=spec.act,
                         pool=pool, block_ci=bci, block_co=bco,
                         interpret=default_interpret())
 
@@ -148,12 +149,13 @@ register_backend("pallas", _conv_pallas, fused=_conv_pallas_fused)
 # layer application (backend-dispatching successor of builder._apply)
 # ---------------------------------------------------------------------------
 
-def apply_conv(spec: LayerSpec, p, x: jax.Array, relu: bool,
+def apply_conv(spec: LayerSpec, p, x: jax.Array,
                pad_w: tuple[int, int] = (0, 0),
                backend: str | None = None,
                pool_spec: LayerSpec | None = None) -> jax.Array:
-    """Apply one conv epilogue chain (conv + bias + relu + optional
-    non-overlapping max-pool) to an NHWC tile.
+    """Apply one conv epilogue chain (conv + bias + the layer's own
+    activation ``spec.act`` + optional non-overlapping max-pool) to an
+    NHWC tile.
 
     Backends with a fused lowering execute the whole chain as one
     kernel call; others compose the exact eager sequence, so a backend
@@ -164,10 +166,8 @@ def apply_conv(spec: LayerSpec, p, x: jax.Array, relu: bool,
     name = backend or DEFAULT_BACKEND
     fused = _FUSED.get(name)
     if fused is not None:
-        return fused(spec, pool_spec, p, x, pad_w, relu)
-    y = get_backend(name)(spec, p, x, pad_w) + p["b"]
-    if relu:
-        y = jax.nn.relu(y)
+        return fused(spec, pool_spec, p, x, pad_w)
+    y = activation(get_backend(name)(spec, p, x, pad_w) + p["b"], spec.act)
     if pool_spec is not None:
         y = jax.lax.reduce_window(
             y, -jnp.inf, jax.lax.max,
@@ -178,7 +178,17 @@ def apply_conv(spec: LayerSpec, p, x: jax.Array, relu: bool,
     return y
 
 
-def apply_layer(spec: LayerSpec, p, x: jax.Array, relu: bool,
+def reorg(x: jax.Array, block: tuple[int, int]) -> jax.Array:
+    """Space-to-depth of an NHWC tile: output ``(i, j)`` holds input
+    ``(i*bh + dy, j*bw + dx)`` at channel ``(dy*bw + dx)*C + c``."""
+    bw, bh = block
+    n, h, w, c = x.shape
+    x = x.reshape(n, h // bh, bh, w // bw, bw, c)
+    return x.transpose(0, 1, 3, 2, 4, 5).reshape(n, h // bh, w // bw,
+                                                 bh * bw * c)
+
+
+def apply_layer(spec: LayerSpec, p, x: jax.Array,
                 pad_w: tuple[int, int] = (0, 0),
                 backend: str | None = None) -> jax.Array:
     """Apply one layer to an NHWC tile.
@@ -190,7 +200,7 @@ def apply_layer(spec: LayerSpec, p, x: jax.Array, relu: bool,
     """
     ph = spec.padding[1]
     if spec.kind == "conv":
-        return apply_conv(spec, p, x, relu, pad_w, backend)
+        return apply_conv(spec, p, x, pad_w, backend)
     if spec.kind == "pool":
         return jax.lax.reduce_window(
             x, -jnp.inf, jax.lax.max,
@@ -198,6 +208,8 @@ def apply_layer(spec: LayerSpec, p, x: jax.Array, relu: bool,
             window_strides=(1, spec.stride[1], spec.stride[0], 1),
             padding=((0, 0), (ph, ph), pad_w, (0, 0)),
         )
+    if spec.kind == "reorg":
+        return reorg(x, spec.kernel)
     if spec.kind == "gpool":
         return jnp.mean(x, axis=(1, 2), keepdims=True)
     if spec.kind == "fc":

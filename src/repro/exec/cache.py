@@ -126,25 +126,28 @@ def static_stage_key(model, nodes, plans, needs) -> tuple:
             tile_signature(plans), tuple(needs))
 
 
-def stage_cache_key(model, nodes, plans, needs, *, backend, relu, donate,
+def stage_cache_key(model, nodes, plans, needs, *, backend, donate,
                     boundary: Mapping, static_key: tuple | None = None,
                     fuse: bool = True) -> tuple:
+    """The static key (whose segment signature holds every layer's
+    activation), then the backend, donation, fusion and boundary
+    shapes and dtypes."""
     shapes = tuple((k, tuple(boundary[k].shape), str(boundary[k].dtype))
                    for k in needs)
     if static_key is None:
         static_key = static_stage_key(model, nodes, plans, needs)
-    return (*static_key, backend, relu, bool(donate), bool(fuse), shapes)
+    return (*static_key, backend, bool(donate), bool(fuse), shapes)
 
 
 def compiled_stage(model, nodes, plans, needs: Sequence, sinks: Sequence,
-                   *, backend: str | None, relu: bool, donate: bool,
+                   *, backend: str | None, donate: bool,
                    boundary: Mapping, static_key: tuple | None = None,
                    fuse: bool = True, name: str = "stage") -> CompiledStage:
     """Fetch-or-build the executable for one stage + boundary shapes.
     ``name`` scopes the ops a miss lowers (``jax.named_scope``); it is
     not part of the key, so identical stages share one executable."""
     key = stage_cache_key(model, nodes, plans, needs, backend=backend,
-                          relu=relu, donate=donate, boundary=boundary,
+                          donate=donate, boundary=boundary,
                           static_key=static_key, fuse=fuse)
     hit = _CACHE.get(key)
     tr = obs_trace.current()
@@ -161,7 +164,7 @@ def compiled_stage(model, nodes, plans, needs: Sequence, sinks: Sequence,
                    hit=False)
     t0 = _time.perf_counter()
     cs = CompiledStage(model, nodes, plans, needs, sinks, backend=backend,
-                       relu=relu, donate=donate, fuse=fuse, name=name)
+                       donate=donate, fuse=fuse, name=name)
     default_registry().histogram("exec.compile.build_s").observe(
         _time.perf_counter() - t0)
     _CACHE[key] = cs

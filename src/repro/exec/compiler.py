@@ -26,6 +26,7 @@ from typing import Mapping, Sequence
 
 import jax
 
+from ..obs.metrics import default_registry
 from ..pipeline.halo import (TilePlan, plan_tiles, split_inputs,
                              stitch_outputs)
 from .backends import DEFAULT_BACKEND, has_fused
@@ -80,7 +81,7 @@ def segment_signature(graph, nodes, input_size) -> tuple:
     nodes = frozenset(nodes)
     layers = tuple(sorted(
         (n, s.kind, s.kernel, s.stride, s.padding, s.in_channels,
-         s.out_channels, s.flops_coeff, s.global_rf)
+         s.out_channels, s.flops_coeff, s.global_rf, s.act)
         for n, s in ((n, graph.layers[n]) for n in nodes)))
     edges = tuple(sorted((u, v) for u, v in graph.edges
                          if u in nodes and v in nodes))
@@ -93,7 +94,7 @@ class CompiledStage:
     def __init__(self, model, nodes, plans: Sequence[TilePlan],
                  needs: Sequence[tuple[str, str | None]],
                  sinks: Sequence[str], *, backend: str | None = None,
-                 relu: bool = True, donate: bool = False,
+                 donate: bool = False,
                  fuse: bool = True, name: str = "stage"):
         self.model = model
         # the traced body runs under jax.named_scope(name) (each layer
@@ -105,7 +106,6 @@ class CompiledStage:
         self.needs = list(needs)
         self.sinks = list(sinks)
         self.backend = backend
-        self.relu = relu
         # conv->pool chains lowered as one fused kernel call; only for
         # backends with a fused lowering (xla keeps the composed-op
         # sequence and with it bit-equality vs the eager oracle)
@@ -119,6 +119,14 @@ class CompiledStage:
         dn = tuple(range(1, 1 + len(self.needs))) if self.donate else ()
         self._fn = jax.jit(self._run, donate_argnums=dn)
         self._scan_fn = jax.jit(self._run_frames, donate_argnums=dn)
+        # what the stage computes, by layer kind and (for convs) the
+        # activation its epilogue applies: a layer that silently fell
+        # back to another activation shows here
+        reg = default_registry()
+        for n in self.nodes:
+            spec = model.graph.layers[n]
+            act = spec.act if spec.kind == "conv" else "none"
+            reg.counter("stage.layers", kind=spec.kind, act=act).inc()
 
     # traced bodies ------------------------------------------------------
 
@@ -134,7 +142,7 @@ class CompiledStage:
                 tiles_out.append(self.model.run_segment(
                     params, self.nodes, tin,
                     ranges=(tp.out_ranges, tp.in_ranges),
-                    relu=self.relu, backend=self.backend,
+                    backend=self.backend,
                     fusion=self.fusion))
             return stitch_outputs(self.plans, self.sinks, tiles_out)
 
@@ -155,7 +163,7 @@ class CompiledStage:
         return self._scan_fn(params, *(boundary[k] for k in self.needs))
 
 def compile_stage(model, nodes, fractions: Sequence[float], *,
-                  backend: str | None = None, relu: bool = True,
+                  backend: str | None = None,
                   donate: bool = False, fuse: bool = True,
                   spec=None) -> CompiledStage:
     """Convenience: plan tiles for ``fractions`` and compile the stage.
@@ -168,5 +176,5 @@ def compile_stage(model, nodes, fractions: Sequence[float], *,
     plans = plan_tiles(g, nodes, model.full_sizes, model.input_size,
                        list(fractions))
     return CompiledStage(model, nodes, plans, model.boundary_needs(nodes),
-                         g.sinks(nodes), backend=backend, relu=relu,
+                         g.sinks(nodes), backend=backend,
                          donate=donate, fuse=fuse)

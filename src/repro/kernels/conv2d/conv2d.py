@@ -26,9 +26,10 @@ Supported conv space:
   block in the wrapper (zeros contribute nothing to the accumulation and
   the padded out-channel tail is sliced off), so the MXU block size never
   degrades to a tiny divisor tile for channel tails;
-* a fused epilogue executed inside the accumulator emit: bias add, relu,
-  and an optional non-overlapping max-pool (kernel == stride, e.g. 2x2),
-  all in fp32 before the final cast, so a conv->bias->relu->pool chain is
+* a fused epilogue executed inside the accumulator emit: bias add, the
+  activation (relu, leaky relu or none), and an optional non-overlapping
+  max-pool (kernel == stride, e.g. 2x2), all in fp32 before the final
+  cast, so a conv->bias->activation->pool chain is
   one Pallas call with no VMEM round-trips between the ops.  Bands hold a
   multiple of the pool height, so a band pools on the global pool grid.
 
@@ -47,6 +48,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.graph import LEAKY_SLOPE
+
 # GEMM rows (band rows x output width) per grid step: large enough to
 # keep the MXU fed, small enough that the f32 accumulator (<= 1 MiB at
 # a 128-wide out-channel block) and the input band fit the scoped VMEM
@@ -54,7 +57,7 @@ _BAND_M = 2048
 
 
 def _conv2d_kernel(*refs, kh: int, kw: int, rows: int, w_out: int,
-                   n_ci_blocks: int, relu: bool,
+                   n_ci_blocks: int, act: str,
                    pool: tuple[int, int] | None, has_bias: bool):
     if has_bias:
         x_ref, w_ref, b_ref, o_ref, acc_ref = refs
@@ -82,8 +85,10 @@ def _conv2d_kernel(*refs, kh: int, kw: int, rows: int, w_out: int,
         y = acc.reshape(rows, w_out, -1)
         if b_ref is not None:
             y = y + b_ref[0]
-        if relu:
+        if act == "relu":
             y = jnp.maximum(y, 0.0)
+        elif act == "leaky":
+            y = jnp.where(y >= 0.0, y, LEAKY_SLOPE * y)
         if pool is not None:
             ph, pw = pool
             wp = w_out // pw
@@ -149,16 +154,17 @@ def _pick_block(c: int, pref: int = 128) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "stride", "relu", "pool", "block_ci", "block_co", "interpret"))
+    "stride", "act", "pool", "block_ci", "block_co", "interpret"))
 def conv2d_fused(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
-                 stride: tuple[int, int] = (1, 1), relu: bool = False,
+                 stride: tuple[int, int] = (1, 1), act: str = "linear",
                  pool: tuple[int, int] | None = None,
                  block_ci: int | None = None, block_co: int | None = None,
                  interpret: bool = False) -> jax.Array:
     """x: (N, H, W, CI); w: (KH, KW, CI, CO); b: (CO,) or None.
 
     Strided VALID conv with the fused epilogue described in the module
-    docstring.  ``pool`` is the max-pool window (== its stride); the
+    docstring.  ``act`` is ``"relu"``, ``"leaky"`` or ``"linear"`` (no
+    activation).  ``pool`` is the max-pool window (== its stride); the
     pooled output is ``(H_out // ph, W_out // pw)`` — identical to a
     VALID non-overlapping ``lax.reduce_window``.  ``block_ci`` /
     ``block_co`` override the channel block sizes (autotune winners).
@@ -199,7 +205,7 @@ def conv2d_fused(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
     grid = (N, n_h, n_co, n_ci)
     kernel = functools.partial(
         _conv2d_kernel, kh=KH, kw=KW, rows=rows, w_out=WO,
-        n_ci_blocks=n_ci, relu=relu, pool=pool, has_bias=b is not None)
+        n_ci_blocks=n_ci, act=act, pool=pool, has_bias=b is not None)
     in_specs = [
         pl.BlockSpec((None, None, rows + KH - 1, W, tci),
                      lambda n, h, co, ci: (n, h, 0, 0, ci)),

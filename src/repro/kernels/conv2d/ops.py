@@ -63,13 +63,13 @@ def normalize_stride(stride) -> tuple[int, int]:
 
 
 def conv2d_fused(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
-                 stride=(1, 1), relu: bool = False,
+                 stride=(1, 1), act: str = "linear",
                  pool: tuple[int, int] | None = None,
                  block_ci: int | None = None, block_co: int | None = None,
                  use_pallas: bool = True, interpret: bool = False
                  ) -> jax.Array:
-    """VALID NHWC conv with a fused epilogue (bias + relu + optional
-    non-overlapping max-pool) in one Pallas call.
+    """VALID NHWC conv with a fused epilogue (bias + activation ``act`` +
+    optional non-overlapping max-pool) in one Pallas call.
 
     The implicit-GEMM kernel handles any stride >= 1 and any channel
     count (tails are zero-padded up to the channel block); the only
@@ -88,13 +88,13 @@ def conv2d_fused(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
     if pool is not None:
         pool = tuple(int(p) for p in pool)
     if not use_pallas:
-        return conv2d_fused_ref(x, w, b, stride=stride, relu=relu, pool=pool)
+        return conv2d_fused_ref(x, w, b, stride=stride, act=act, pool=pool)
     if H < KH or W < KW:
         _fallback("shape", tuple(x.shape), tuple(w.shape), stride,
                   f"conv2d: input {x.shape} smaller than kernel {w.shape}; "
                   "falling back to the XLA reference")
-        return conv2d_fused_ref(x, w, b, stride=stride, relu=relu, pool=pool)
-    return _conv2d_fused_pallas(x, w, b, stride=stride, relu=relu, pool=pool,
+        return conv2d_fused_ref(x, w, b, stride=stride, act=act, pool=pool)
+    return _conv2d_fused_pallas(x, w, b, stride=stride, act=act, pool=pool,
                                 block_ci=block_ci, block_co=block_co,
                                 interpret=interpret)
 

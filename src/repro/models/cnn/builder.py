@@ -6,8 +6,9 @@ executable JAX forward over any *segment* of the graph — which is what
 the pipeline runtime executes per stage, on halo-extended input tiles.
 
 Only layer kinds that change feature geometry or carry weights are
-vertices (conv/pool/fc/add/concat); norm/activation are fused into the
-conv vertex (the paper ignores them for the same reason, §2.3).
+vertices (conv/pool/fc/add/concat/reorg); norm/activation are fused into
+the conv vertex (the paper ignores them for the same reason, §2.3): each
+conv applies its own ``LayerSpec.act`` after its bias.
 """
 
 from __future__ import annotations
@@ -94,7 +95,6 @@ class CNNDef:
         inputs: Mapping[tuple[str, str | None], jax.Array],
         ranges: tuple[Mapping[str, tuple[int, int]],
                       Mapping[str, tuple[int, int]]] | None = None,
-        relu: bool = True,
         backend: str | None = None,
         fusion: Mapping[str, str] | None = None,
     ) -> dict[str, jax.Array]:
@@ -118,7 +118,8 @@ class CNNDef:
 
         Each node runs under ``jax.named_scope(<node>)``, so the ops a
         compile lowers name their layer (a fused pair under the conv's
-        name); the computation is unchanged.
+        name, a conv's activation under the conv's); the computation is
+        unchanged.
 
         Returns {sink: tile covering ranges[0][sink] along W}.
         """
@@ -180,19 +181,19 @@ class CNNDef:
                 if spec.kind == "conv" and n in fusion \
                         and fused_ranges_ok(n, fusion[n]):
                     vals[fusion[n]] = apply_conv(
-                        spec, params.get(n), xs[0], relu, pad_w,
+                        spec, params.get(n), xs[0], pad_w,
                         backend=backend, pool_spec=g.layers[fusion[n]])
                     continue
-                vals[n] = apply_layer(spec, params.get(n), xs[0], relu,
-                                      pad_w, backend=backend)
+                vals[n] = apply_layer(spec, params.get(n), xs[0], pad_w,
+                                      backend=backend)
         return {s: vals[s] for s in g.sinks(nodes)}
 
-    def forward(self, params, image: jax.Array, relu: bool = True,
+    def forward(self, params, image: jax.Array,
                 backend: str | None = None):
         """Monolithic forward over the whole graph (reference path)."""
         srcs = self.graph.sources()
         outs = self.run_segment(params, set(self.graph.layers),
-                                {(s, None): image for s in srcs}, relu=relu,
+                                {(s, None): image for s in srcs},
                                 backend=backend)
         return outs
 
@@ -236,8 +237,9 @@ class GB:
     def _src_size(self, src):
         return self.sz[src] if src else self.d.input_size
 
-    def conv(self, src, cout, k=3, s=1, p=0, name=None):
-        """p may be an int or (pw, ph); 'same' means k//2."""
+    def conv(self, src, cout, k=3, s=1, p=0, name=None, act="relu"):
+        """p may be an int or (pw, ph); 'same' means k//2.  ``act`` is
+        the activation after the bias (``core.graph.ACTIVATIONS``)."""
         cin = self.ch[src] if src else self.d.in_channels
         kk = k if isinstance(k, tuple) else (k, k)
         ss = s if isinstance(s, tuple) else (s, s)
@@ -246,7 +248,8 @@ class GB:
         pp = p if isinstance(p, tuple) else (p, p)
         name = name or self._name("conv")
         spec = LayerSpec(name, "conv", kk, ss, pp, cin, cout,
-                         param_bytes=4 * (kk[0] * kk[1] * cin * cout + cout))
+                         param_bytes=4 * (kk[0] * kk[1] * cin * cout + cout),
+                         act=act)
         self.d.graph.add(spec, [src] if src else [])
         self.ch[name] = cout
         self.sz[name] = spec.out_size(self._src_size(src))
@@ -263,6 +266,17 @@ class GB:
         spec = LayerSpec(name, "pool", kk, ss, pp, cin, cin)
         self.d.graph.add(spec, [src])
         self.ch[name] = cin
+        self.sz[name] = spec.out_size(self._src_size(src))
+        return name
+
+    def reorg(self, src, name=None):
+        """Space-to-depth by 2 (YOLOv2's passthrough): each 2x2 block of
+        pixels moves into channels, ``(dy, dx, c)`` order."""
+        cin = self.ch[src]
+        name = name or self._name("reorg")
+        spec = LayerSpec(name, "reorg", (2, 2), (2, 2), (0, 0), cin, 4 * cin)
+        self.d.graph.add(spec, [src])
+        self.ch[name] = spec.out_channels
         self.sz[name] = spec.out_size(self._src_size(src))
         return name
 

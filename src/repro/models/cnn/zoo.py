@@ -3,8 +3,9 @@
 Padding is explicit geometry (SAME where the original models use it) and
 the range machinery makes halo-tiled execution bit-exact, including each
 tile's share of boundary zero padding.  Structure classes per the paper:
-chain (VGG16, YOLOv2), block (ResNet34, InceptionV3, SqueezeNet,
-MobileNetV3), graph (NASNet-style cells).
+chain (VGG16), block (YOLOv2, whose passthrough is a long skip over the
+last stage; ResNet34, InceptionV3, SqueezeNet, MobileNetV3), graph
+(NASNet-style cells).
 
 ``scale`` shrinks channel counts for fast CPU tests.
 """
@@ -38,28 +39,38 @@ def vgg16(input_size=(224, 224), scale: float = 1.0,
     return b.done()
 
 
-def yolov2(input_size=(448, 448), scale: float = 1.0) -> CNNDef:
-    """Darknet-19 trunk + detection convs: 23 conv, 5 pool (chain)."""
+def yolov2(input_size=(608, 608), scale: float = 1.0) -> CNNDef:
+    """YOLOv2 as darknet's ``cfg/yolov2.cfg`` lists it (Redmon & Farhadi,
+    arXiv:1612.08242): Darknet-19's 18 convs and 5 max-pools, two 3x3
+    convs on top (M), the passthrough — a 1x1 route conv on the last
+    38x38 conv at 608 (R), space-to-depth by 2, concatenated in front of
+    M — then a 3x3 conv and the linear 1x1 detection conv of 5 anchors x
+    (80 classes + 5).  23 convs; leaky ReLU (slope 0.1) after every conv
+    but the last.  BatchNorm is taken as folded into conv + bias.
+
+    Each 1x1 conv has half its stage's scaled 3x3 width and the route
+    an eighth of R's, the published widths at ``scale`` 1."""
     b = GB("yolov2", input_size)
-    x = b.conv(None, _c(32, scale), 3, p=1)
-    x = b.pool(x)
-    x = b.conv(x, _c(64, scale), 3, p=1)
-    x = b.pool(x)
-    for ch in (128, 64, 128):
-        x = b.conv(x, _c(ch, scale), 3 if ch != 64 else 1, p="same")
-    x = b.pool(x)
-    for ch in (256, 128, 256):
-        x = b.conv(x, _c(ch, scale), 3 if ch != 128 else 1, p="same")
-    x = b.pool(x)
-    for ch in (512, 256, 512, 256, 512):
-        x = b.conv(x, _c(ch, scale), 3 if ch != 256 else 1, p="same")
-    x = b.pool(x)
-    for ch in (1024, 512, 1024, 512, 1024):
-        x = b.conv(x, _c(ch, scale), 3 if ch != 512 else 1, p="same")
-    # detection head
-    x = b.conv(x, _c(1024, scale), 3, p=1)
-    x = b.conv(x, _c(1024, scale), 3, p=1)
-    x = b.conv(x, 425, 1)
+
+    def conv(x, ch, k):
+        return b.conv(x, ch, k, p="same", act="leaky")
+
+    x = conv(None, _c(32, scale), 3)
+    route = None
+    for reps, ch in ((1, 64), (3, 128), (3, 256), (5, 512), (5, 1024)):
+        x = b.pool(x)
+        wide = _c(ch, scale)
+        for i in range(reps):
+            x = conv(x, wide, 3) if i % 2 == 0 else \
+                conv(x, max(1, wide // 2), 1)
+        if ch == 512:
+            route = x                   # R: feeds the pool and the route
+    for _ in range(2):
+        x = conv(x, _c(1024, scale), 3)
+    r = conv(route, max(1, b.ch[route] // 8), 1)
+    x = b.concat([b.reorg(r), x])       # darknet's route -1,-4 order
+    x = conv(x, _c(1024, scale), 3)
+    b.conv(x, 5 * (80 + 5), 1, act="linear")
     return b.done()
 
 

@@ -106,7 +106,7 @@ class StageExecutor:
         from ..exec.cache import compiled_stage
         return compiled_stage(self.model, self.nodes, self.plans,
                               self.needs, self.sinks, backend=self.backend,
-                              relu=True, donate=self.donate,
+                              donate=self.donate,
                               boundary=boundary, static_key=self._static_key,
                               fuse=self.fuse, name=self.name)
 

@@ -38,7 +38,7 @@ def test_shape_key_is_spatial_size_agnostic():
     # but channels, stride, epilogue and backend all distinguish
     assert a != shape_key((1, 32, 32, 9), (3, 3, 9, 16), (1, 1))
     assert a != shape_key((1, 32, 32, 8), (3, 3, 8, 16), (2, 2))
-    assert a != shape_key((1, 32, 32, 8), (3, 3, 8, 16), (1, 1), relu=True)
+    assert a != shape_key((1, 32, 32, 8), (3, 3, 8, 16), (1, 1), act="relu")
     assert a != shape_key((1, 32, 32, 8), (3, 3, 8, 16), (1, 1),
                           pool=(2, 2))
     assert a != shape_key((1, 32, 32, 8), (3, 3, 8, 16), (1, 1),
@@ -47,7 +47,7 @@ def test_shape_key_is_spatial_size_agnostic():
 
 def test_autotune_conv_picks_a_candidate():
     res = autotune_conv((1, 10, 10, 5), (3, 3, 5, 7), stride=(1, 1),
-                        relu=True, pool=(2, 2), candidates=TINY, iters=1)
+                        act="relu", pool=(2, 2), candidates=TINY, iters=1)
     assert (res.block_ci, res.block_co) in TINY
     assert len(res.trials) == len(TINY)
     assert res.best_us > 0
@@ -74,7 +74,7 @@ def test_conv_shapes_fuses_like_the_compiler():
     assert len(shapes) <= sum(
         1 for s in m.graph.layers.values() if s.kind == "conv")
     assert any(d["pool"] for d in shapes)   # vgg conv->pool chains fuse
-    assert all(d["relu"] for d in shapes)
+    assert all(d["act"] == "relu" for d in shapes)
 
 
 def test_autotune_model_skips_warm_table_entries():

@@ -89,14 +89,15 @@ def test_conv2d_channel_tail_blocks(shape, blocks):
 ])
 def test_conv2d_fused_epilogue(shape, relu, pool):
     """Fused bias+relu(+pool) inside the kernel == composed oracle."""
+    act = "relu" if relu else "linear"
     n, h, w, ci, co, kh, kw, stride = shape
     x = jax.random.normal(jax.random.PRNGKey(0), (n, h, w, ci), jnp.float32)
     wt = jax.random.normal(jax.random.PRNGKey(1), (kh, kw, ci, co),
                            jnp.float32) / np.sqrt(kh * kw * ci)
     b = jax.random.normal(jax.random.PRNGKey(2), (co,), jnp.float32)
-    out = conv2d_fused(x, wt, b, stride=stride, relu=relu, pool=pool,
+    out = conv2d_fused(x, wt, b, stride=stride, act=act, pool=pool,
                        interpret=True)
-    ref = conv2d_fused_ref(x, wt, b, stride=stride, relu=relu, pool=pool)
+    ref = conv2d_fused_ref(x, wt, b, stride=stride, act=act, pool=pool)
     assert out.shape == ref.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                **TOL[jnp.float32])
@@ -119,9 +120,9 @@ def test_conv2d_row_bands(shape):
     wt = jax.random.normal(jax.random.PRNGKey(1), (kh, kw, ci, co),
                            jnp.float32) / np.sqrt(kh * kw * ci)
     b = jax.random.normal(jax.random.PRNGKey(2), (co,), jnp.float32)
-    out = conv2d_fused(x, wt, b, stride=stride, relu=True, pool=pool,
+    out = conv2d_fused(x, wt, b, stride=stride, act="relu", pool=pool,
                        interpret=True)
-    ref = conv2d_fused_ref(x, wt, b, stride=stride, relu=True, pool=pool)
+    ref = conv2d_fused_ref(x, wt, b, stride=stride, act="relu", pool=pool)
     assert out.shape == ref.shape
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                **TOL[jnp.float32])

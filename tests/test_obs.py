@@ -202,8 +202,13 @@ def test_conv_fallback_is_structured():
 
     # strided convs now run on the Pallas kernel; the one remaining
     # fallback is an input spatially smaller than the filter
+    from repro.kernels.conv2d.ref import conv2d_ref
     x = jnp.ones((1, 2, 2, 4), jnp.float32)
     w = jnp.ones((3, 3, 4, 8), jnp.float32)
+    # compile the fallback's XLA conv outside the tracer: where an earlier
+    # test in this process installed the compile listener, that compile
+    # would add a ``compile`` span to the one asserted below
+    conv2d_ref(x, w, (1, 1)).block_until_ready()
     before = fallback_count()
     tr = Tracer()
     with obs_trace.scoped(tr), pytest.warns(RuntimeWarning):

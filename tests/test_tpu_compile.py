@@ -23,16 +23,19 @@ from repro.pipeline.stage import StageExecutor
 
 
 def _zoo_conv_cases():
-    """Distinct conv-epilogue shapes of vgg16 and resnet34 at 224x224,
-    published widths, fused the way the compiler fuses them."""
+    """Distinct conv-epilogue shapes of vgg16 and resnet34 at 224x224
+    and yolov2 at 608x608, published widths, fused the way the compiler
+    fuses them; yolov2's carry its leaky or linear epilogue."""
     cases = {}
-    for name in ("vgg16", "resnet34"):
+    for name in ("vgg16", "resnet34", "yolov2"):
         for d in conv_shapes(zoo.build(name)):
-            key = (d["x_shape"], d["w_shape"], d["stride"], d["pool"])
+            key = (d["x_shape"], d["w_shape"], d["stride"], d["pool"],
+                   d["act"])
             cases.setdefault(key, pytest.param(d, id=(
                 f"{name}-x{'x'.join(map(str, d['x_shape'][1:]))}"
                 f"-k{d['w_shape'][0]}-co{d['w_shape'][3]}"
-                f"-s{d['stride'][0]}" + ("-pool" if d["pool"] else ""))))
+                f"-s{d['stride'][0]}" + ("-pool" if d["pool"] else "")
+                + ("" if d["act"] == "relu" else f"-{d['act']}"))))
     return list(cases.values())
 
 
@@ -84,13 +87,12 @@ def test_pallas_conv_compiles_for_v5e(d, one_chip):
     b = jax.ShapeDtypeStruct(d["w_shape"][-1:], jnp.float32,
                              sharding=one_chip)
     compiled = jax.jit(lambda x, w, b: conv2d_fused(
-        x, w, b, stride=d["stride"], relu=True, pool=d["pool"],
+        x, w, b, stride=d["stride"], act=d["act"], pool=d["pool"],
         interpret=False)).lower(x, w, b).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_vgg16_whole_model_xla_stage_compiles_for_v5e(one_chip):
-    m = zoo.vgg16()
+def _whole_model_stage(m, one_chip):
     ex = StageExecutor(m, frozenset(m.graph.layers), [1.0], backend="xla")
 
     def sds(a):
@@ -103,7 +105,21 @@ def test_vgg16_whole_model_xla_stage_compiles_for_v5e(one_chip):
         {}, jax.ShapeDtypeStruct((1, h, w, 3), jnp.float32,
                                  sharding=one_chip))
     stage = ex._executable(boundary)
-    compiled = stage._fn.lower(
+    return stage._fn.lower(
         params, *(boundary[k] for k in stage.needs)).compile()
+
+
+def test_yolov2_whole_model_xla_stage_compiles_for_v5e(one_chip):
+    """The published YOLOv2 at 608 — leaky and linear epilogues, the
+    reorg and the concat — as the one-chip stage the benchmark runs."""
+    m = zoo.yolov2()
+    compiled = _whole_model_stage(m, one_chip)
+    (sink,) = m.graph.sinks()
+    assert compiled.out_info[sink].shape == (1, 19, 19, 425)
+
+
+def test_vgg16_whole_model_xla_stage_compiles_for_v5e(one_chip):
+    m = zoo.vgg16()
+    compiled = _whole_model_stage(m, one_chip)
     (sink,) = m.graph.sinks()
     assert compiled.out_info[sink].shape == (1, 1, 1, 1000)
