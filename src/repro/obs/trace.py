@@ -77,7 +77,7 @@ SPAN_NAMES = (
     "dist.launch",      # dist worker spawn + handshake + warmup probe
     "dist.churn",       # dist worker declared dead (heartbeat/link/error)
     "run",              # one Deployment.run call
-    "run.stack",        # Deployment.run: frames stacked onto the device (src)
+    "run.stack",        # Deployment.run: a chunk stacked onto the device
     "run.split",        # Deployment.run: stacked sinks unstacked per frame
     "stage",            # one stage's dispatch (StageExecutor call)
     "dist.submit",      # launcher: one frame encoded onto the feed link

@@ -17,7 +17,7 @@ import pytest
 import repro
 from repro.api import (DeploySpec, ExecSpec, PlanSpec, artifacts,
                        reset_legacy_warnings)
-from repro.api.deployment import stack_frames
+from repro.api.deployment import FEED_CHUNK, feed_chunks, stack_frames
 from repro.core import (CostTable, make_pi_cluster, plan, replan, simulate)
 from repro.core.partition import PartitionResult
 from repro.models.cnn import zoo
@@ -372,6 +372,53 @@ def test_run_scan_frames_bit_exact_with_per_frame(scan_pair, kind):
             assert a[k].shape == b[k].shape and a[k].dtype == b[k].dtype
             np.testing.assert_array_equal(np.asarray(a[k]),
                                           np.asarray(b[k]))
+
+
+@pytest.fixture(scope="module", params=[1, 2], ids=["1-stage", "2-stage"])
+def chunk_pair(request):
+    """A scanning deployment of ``request.param`` stages and its
+    per-frame twin on the same weights."""
+    m = _tiny("squeezenet", size=48)
+    cluster = make_pi_cluster([1.5, 1.0][:request.param])
+    dep = repro.compile(m, cluster).load_params()
+    looped = repro.compile(m, cluster,
+                           exec_spec=ExecSpec(scan_batch=False))
+    looped.params = dep.params
+    assert len(dep.pico.pipeline.stages) == request.param
+    return dep, looped
+
+
+@pytest.mark.parametrize("sizes", [
+    [FEED_CHUNK], [FEED_CHUNK] * 2, [FEED_CHUNK, 3], [FEED_CHUNK] * 2 + [1]],
+    ids=["one-chunk", "even", "ragged", "ragged-1"])
+def test_run_chunked_host_feed_bit_exact(chunk_pair, sizes):
+    dep, looped = chunk_pair
+    n = sum(sizes)
+    host = _host_frames(n)
+    assert [len(c) for c in feed_chunks(host)] == sizes
+    fed = dep.run(host)
+    one_stack = dep.run([jnp.asarray(x) for x in host])
+    plain = looped.run(host)
+    assert len(fed) == len(one_stack) == len(plain) == n
+    for a, b, c in zip(fed, one_stack, plain):
+        assert list(a) == list(b) == list(c)
+        for k in a:
+            np.testing.assert_array_equal(np.asarray(a[k]),
+                                          np.asarray(b[k]))
+            np.testing.assert_array_equal(np.asarray(a[k]),
+                                          np.asarray(c[k]))
+
+
+def test_feed_chunks_one_chunk_unless_uniform_host_frames():
+    n = 2 * FEED_CHUNK + 1
+    host = _host_frames(n, shape=_SMALL)
+    assert len(feed_chunks(host)) == 3
+    for odd in ([jnp.asarray(x) for x in host],
+                host[:-1] + [jnp.asarray(host[-1])],
+                host[:-1] + [host[-1].astype(np.float16)],
+                host[:-1] + [host[-1][..., :2]]):
+        (only,) = feed_chunks(odd)
+        assert only is odd
 
 
 def test_run_float64_frames_canonicalised_as_jnp_stack(scan_pair):

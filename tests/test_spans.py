@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import repro
+from repro.api.deployment import FEED_CHUNK
 from repro.api.specs import DistSpec, ExecSpec
 from repro.core import make_pi_cluster
 from repro.dist import make_frames
@@ -56,48 +57,64 @@ def _call_spans(dep, call):
 
 def test_deployment_run_records_one_span_tree(dep3):
     host = list(make_frames(dep3.model, 32))
-    n_sinks = len(dep3.model.graph.sinks())
-    for frames, src in ((host, "host"),
-                        ([jnp.asarray(x) for x in host], "device")):
+    n_stages = len(dep3.pico.pipeline.stages)
+    reg = default_registry()
+    n_host = math.ceil(32 / FEED_CHUNK)
+    assert n_host > 1
+    for frames, src, n_chunks in (
+            (host, "host", n_host),
+            ([jnp.asarray(x) for x in host], "device", 1)):
         dep3.run(frames)                               # warm
         call = dep3._calls + 1
+        fed = reg.total("run.feed_chunks")
         outs = dep3.run(frames)
+        assert reg.total("run.feed_chunks") - fed == n_chunks
         assert len(outs) == 32
         run, kids = _call_spans(dep3, call)
         assert run.attr("frames") == 32
+        assert run.attr("chunks") == n_chunks
         names = sorted(s.name for s in kids)
-        n_stages = len(dep3.pico.pipeline.stages)
-        assert names == sorted(["run.stack", "run.split"]
-                               + ["stage"] * n_stages)
+        assert names == sorted(["run.stack"] * n_chunks + ["run.split"]
+                               + ["stage"] * (n_stages * n_chunks))
         for s in kids:
             assert _inside(s, run)
             if s.name != "stage":
                 assert s.attr("call") == call
-        stack, = [s for s in kids if s.name == "run.stack"]
-        split, = [s for s in kids if s.name == "run.split"]
-        stages = sorted((s for s in kids if s.name == "stage"),
-                        key=lambda s: s.ts)
-        assert [s.attr("stage") for s in stages] == \
-            [f"stage{i}" for i in range(n_stages)]
-        assert stack.end <= stages[0].ts and stages[-1].end <= split.ts
-        assert stack.attr("src") == src
-        assert 1 <= split.attr("dispatches") <= n_sinks * math.ceil(32 / 100)
+
+        def by_ts(name):
+            return sorted((s for s in kids if s.name == name),
+                          key=lambda s: s.ts)
+        stacks, stages = by_ts("run.stack"), by_ts("stage")
+        split, = by_ts("run.split")
+        assert [s.attr("chunk") for s in stacks] == list(range(n_chunks))
+        assert all(s.attr("src") == src for s in stacks)
+        for k, stack in enumerate(stacks):
+            mine = stages[k * n_stages:(k + 1) * n_stages]
+            assert [s.attr("stage") for s in mine] == \
+                [f"stage{i}" for i in range(n_stages)]
+            assert stack.end <= mine[0].ts
+            if k + 1 < n_chunks:
+                assert mine[-1].end <= stacks[k + 1].ts
+        assert stages[-1].end <= split.ts
+        assert split.attr("dispatches") == 1
 
 
 def test_same_shape_run_adds_no_compile(dep3):
     reg = default_registry()
-    frames = list(make_frames(dep3.model, 5))
-    before = reg.total("xla.compiles")
-    dep3.run(frames)                     # a new scan length: compiles
-    assert reg.total("xla.compiles") > before
-    assert reg.total("xla.compile_s") > 0.0
-    assert any(s.name == "compile" and s.attr("fun")
-               for s in dep3.tracer.spans)
-    n_spans = len(dep3.tracer.by_name("compile"))
-    n_compiles = reg.total("xla.compiles")
-    dep3.run(frames)
-    assert len(dep3.tracer.by_name("compile")) == n_spans
-    assert reg.total("xla.compiles") == n_compiles
+    # a scan length of its own; then chunks with a ragged tail
+    for n in (5, FEED_CHUNK + 3):
+        frames = list(make_frames(dep3.model, n))
+        before = reg.total("xla.compiles")
+        dep3.run(frames)                 # a new scan length: compiles
+        assert reg.total("xla.compiles") > before
+        assert reg.total("xla.compile_s") > 0.0
+        assert any(s.name == "compile" and s.attr("fun")
+                   for s in dep3.tracer.spans)
+        n_spans = len(dep3.tracer.by_name("compile"))
+        n_compiles = reg.total("xla.compiles")
+        dep3.run(frames)
+        assert len(dep3.tracer.by_name("compile")) == n_spans
+        assert reg.total("xla.compiles") == n_compiles
 
 
 def _by_fid(spans, fid):
