@@ -32,6 +32,7 @@ import time
 import jax
 
 from .common import csv_row, paper_cluster
+from repro.api import ExecSpec
 from repro.core import plan, replan
 from repro.exec import cache_stats, calibrate_plan, clear_cache
 from repro.models.cnn import zoo
@@ -84,7 +85,7 @@ def run(smoke: bool = False) -> list[str]:
     # ---- full PICO plan, stage by stage ------------------------------
     clear_cache()            # report this section's cache behavior alone
     pico = plan(m.graph, cluster, m.input_size)
-    eager_pl = PipelineRunner(m, pico.pipeline, mode="eager")
+    eager_pl = PipelineRunner(m, pico.pipeline, ExecSpec(mode="eager"))
     comp_pl = PipelineRunner(m, pico.pipeline)
     t_pe = _time_per_frame(lambda f: eager_pl(params, f), frames)
     rows.append(csv_row(f"exec/{m.name}_pipeline_eager", t_pe * 1e6,

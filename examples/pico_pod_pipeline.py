@@ -1,7 +1,7 @@
 """PICO at pod scale: plan a decoder's pipeline split with the PICO DP,
 then EXECUTE it as a GPipe-style shard_map pipeline over a mesh axis —
 the form the paper's technique takes on TPU pods, where stage-boundary
-activations are the only cross-group traffic (DESIGN.md §5).
+activations are the only cross-group traffic.
 
 Runs on 8 host devices (set before jax import) and verifies the
 pipelined result equals the monolithic forward bit-for-bit.
@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.api import PlanSpec
 from repro.core import make_tpu_cluster, plan
 from repro.models.graph_export import export_graph
 from repro.models.transformer import model as M
@@ -30,7 +31,8 @@ cfg = configs.get("llama3.2-1b").reduced(n_layers=8, d_model=128)
 
 # 1. PICO plans the stage split (graph export -> Alg.1 pieces -> Alg.2)
 g = export_graph(cfg, seq_len=64)
-pico = plan(g, make_tpu_cluster(N_STAGES), (64, 1), max_diameter=2)
+pico = plan(g, make_tpu_cluster(N_STAGES), (64, 1),
+            spec=PlanSpec(max_diameter=2))
 print(f"PICO split {cfg.n_layers} layers into "
       f"{len(pico.pipeline.stages)} stages; period "
       f"{pico.period*1e6:.1f} us (modeled)")

@@ -2,13 +2,13 @@
 and artifact layers.  Re-exported at top level as ``repro.compile`` /
 ``repro.Deployment`` / ``repro.PlanSpec`` / ...
 
-Only the lightweight pieces (specs, deprecation plumbing) import
-eagerly; :func:`compile`/:class:`Deployment` and the artifact codecs
-load on first touch so ``repro.core`` stays importable without JAX and
+Only the lightweight pieces (the specs) import eagerly;
+:func:`compile`/:class:`Deployment` and the artifact codecs load on
+first touch so ``repro.core`` stays importable without JAX and
 free of import cycles.
 """
 
-from ._compat import lazy_exports, reset_legacy_warnings
+from ._compat import lazy_exports
 from .specs import (OBJECTIVE_PRESETS, SPEC_VERSION, DeploySpec, ExecSpec,
                     FleetSpec, ObjectiveSpec, PlanSpec, spec_from_dict)
 
@@ -22,6 +22,6 @@ _LAZY = {
 __all__ = ["PlanSpec", "ExecSpec", "DeploySpec", "FleetSpec",
            "ObjectiveSpec", "OBJECTIVE_PRESETS", "spec_from_dict",
            "SPEC_VERSION", "SCHEMA_VERSION", "compile", "Deployment",
-           "artifacts", "reset_legacy_warnings"]
+           "artifacts"]
 
 __getattr__, __dir__ = lazy_exports(__name__, globals(), _LAZY)

@@ -85,7 +85,7 @@ def compile(model, cluster: Cluster,
             if params is None:
                 params = _init_params(model, key)
             report = calibrate_plan(model, params, pico.pipeline.stages,
-                                    backend=exec_spec.backend,
+                                    spec=exec_spec,
                                     iters=exec_spec.calibrate_iters)
             tuned = cost_table.kernels if cost_table is not None else {}
             cost_table = report.table()
@@ -260,10 +260,10 @@ class Deployment:
         """Execute frame(s) through the pipelined stages (bit-exact with
         the monolithic forward).  A single array returns one sink dict;
         a sequence returns a list of sink dicts of device arrays.
-        Multi-frame sequences go through the compiled ``lax.scan``
-        ``run_frames`` path (one dispatch per stage and chunk) unless
-        ``exec_spec.scan_batch`` is off; nothing here waits for a
-        result.
+        A single array or a one-frame sequence takes the runner's
+        per-frame ``__call__``; longer sequences go through the
+        compiled ``lax.scan`` ``run_frames`` path (one dispatch per
+        stage and chunk).  Nothing here waits for a result.
 
         The scan path feeds the frames in the chunks of
         :func:`feed_chunks`: NumPy frames in chunks of
@@ -297,7 +297,7 @@ class Deployment:
                 return self.runner(params, frames)
             frames = list(frames)
             span.set(frames=len(frames))
-            if self.exec_spec.scan_batch and len(frames) > 1:
+            if len(frames) > 1:
                 chunks = feed_chunks(frames)
                 span.set(chunks=len(chunks))
                 default_registry().counter("run.feed_chunks").inc(

@@ -6,7 +6,7 @@ entry point used to re-thread:
 
 * :class:`PlanSpec`   — the offline optimizer (Algorithms 1-3) knobs;
 * :class:`ExecSpec`   — how plans lower to executables (backend,
-  compile mode, donation, scan batching, cache limits, calibration);
+  compile mode, cache limits, calibration, autotuning);
 * :class:`DeploySpec` — the online runtime/serving knobs (batching,
   link realism, churn/drift re-planning policy).
 
@@ -308,39 +308,39 @@ class ExecSpec(_SpecBase):
     """Execution-backend configuration for compiled plans.
 
     ``backend`` picks the conv lowering (``exec.backends`` registry;
-    ``None`` = model default).  ``mode`` selects the compiled whole-stage
-    executable or the eager per-tile oracle.  ``donate`` hands boundary
-    buffers to XLA — honored only by single-stage entry points
-    (:func:`repro.exec.compiler.compile_stage`, the exec benchmarks);
-    multi-stage runners share boundary tensors across stages, where
-    donation would corrupt later reads, so they always keep it off.
-    ``scan_batch`` routes multi-frame cohorts through the ``lax.scan``
-    ``run_frames`` path.  ``cache_size`` bounds the *process-wide*
-    executable cache (applied whenever a Deployment carrying the spec
-    is built or loaded).  ``calibrate`` makes :func:`repro.api.compile`
-    time each stage and re-plan on the measured
-    :class:`~repro.core.cost.CostTable`.  ``fuse`` lowers conv->pool
-    chains as one fused kernel call on backends with a fused lowering
-    (numerics-neutral on the others).
-    ``autotune`` makes :func:`repro.api.compile` search the Pallas
-    kernel's channel block sizes per conv shape before calibration and
-    persist the winners in the deployment's CostTable artifact.
+    ``None`` = the model's own, else the registry default).  ``mode``
+    selects the compiled whole-stage executable or the eager per-tile
+    oracle.  ``cache_size`` bounds the *process-wide* executable cache
+    (applied whenever a Deployment carrying the spec is built or
+    loaded).  ``calibrate`` makes :func:`repro.api.compile` time each
+    stage and re-plan on the measured
+    :class:`~repro.core.cost.CostTable`.  ``autotune`` makes
+    :func:`repro.api.compile` search the Pallas kernel's channel block
+    sizes per conv shape before calibration and persist the winners in
+    the deployment's CostTable artifact.
+
+    :func:`repro.pipeline.stage.stage_executor` turns the spec into a
+    stage's executor on every path (runner, runtime, dist workers,
+    calibration, servers).  Executors lower conv->pool chains as one
+    fused kernel call on backends with a fused lowering, and never
+    donate boundary buffers: the stages of a plan share them.  Whether
+    a call scans is read from the call: one frame takes the per-frame
+    path, more take the ``lax.scan`` ``run_frames`` path.
     """
 
     backend: str | None = None
     mode: str = "compiled"
-    donate: bool = False
-    scan_batch: bool = True
     cache_size: int | None = None
     calibrate: bool = False
     calibrate_iters: int = 3
-    fuse: bool = True           # fuse conv->pool chains into one kernel call
     autotune: bool = False      # tune kernel block sizes at compile time
     autotune_iters: int = 3
 
-    #: ``profile`` bracketed stage calls in a profiler annotation; every
-    #: stage call now records a ``stage`` span, which opens one
-    _retired = ("profile",)
+    #: retired: the profiler switch (every stage call now records a
+    #: ``stage`` span, which opens an annotation) and the scan, fusion
+    #: and donation switches (a call's frame count picks the scan; stage
+    #: executors always fuse where the backend can, and never donate)
+    _retired = ("profile", "scan_batch", "fuse", "donate")
 
     def __post_init__(self):
         if self.mode not in _EXEC_MODES:

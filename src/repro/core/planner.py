@@ -11,7 +11,6 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Sequence
 
-from ..api._compat import _UNSET, pick, unset, warn_legacy
 from ..api.specs import PlanSpec
 from ..obs import trace as obs_trace
 from .graph import Graph
@@ -76,7 +75,7 @@ def plan_with_spec(
     """Run the full PICO optimization under a :class:`PlanSpec`.
 
     This is the one implementation every entry point (the ``repro.api``
-    facade, the legacy :func:`plan`/:func:`replan` shims, the runtime's
+    facade, :func:`plan`/:func:`replan`, the runtime's
     churn re-planner, the serving scheduler) funnels into.
 
     Algorithm 1 may be skipped by supplying either raw ``pieces`` (an
@@ -148,38 +147,19 @@ def plan(
     g: Graph,
     cluster: Cluster,
     input_size: tuple[int, int],
-    t_lim: float = _UNSET,
-    max_diameter: int = _UNSET,
-    n_split: int | None = _UNSET,
-    dnc_threshold: int = _UNSET,
     pieces: Sequence[Piece] | None = None,
     cost_table: CostTable | None = None,
     spec: PlanSpec | None = None,
 ) -> PicoPlan:
     """Run the full PICO optimization.
 
-    Planner knobs live in ``spec`` (:class:`~repro.api.specs.PlanSpec`);
-    the individual ``t_lim``/``max_diameter``/``n_split``/
-    ``dnc_threshold`` keywords are a deprecated compatibility surface
-    that maps onto an equivalent spec.  ``pieces`` skips Algorithm 1
-    with a caller-supplied chain; ``cost_table`` substitutes measured
+    Planner knobs live in ``spec`` (:class:`~repro.api.specs.PlanSpec`,
+    default ``PlanSpec()``).  ``pieces`` skips Algorithm 1 with a
+    caller-supplied chain; ``cost_table`` substitutes measured
     per-segment compute costs for the analytic alpha model.
     """
-    legacy = not unset(t_lim, max_diameter, n_split, dnc_threshold)
-    if spec is not None:
-        if legacy:
-            raise TypeError("pass either spec= or the legacy planner "
-                            "kwargs, not both")
-    else:
-        if legacy:
-            warn_legacy("repro.core.plan",
-                        "plan(g, cluster, input_size, spec=PlanSpec(...))")
-        spec = PlanSpec(t_lim=pick(t_lim, float("inf")),
-                        max_diameter=pick(max_diameter, 5),
-                        n_split=pick(n_split, None),
-                        dnc_threshold=pick(dnc_threshold, 120))
-    return plan_with_spec(g, cluster, input_size, spec, pieces=pieces,
-                          cost_table=cost_table)
+    return plan_with_spec(g, cluster, input_size, spec or PlanSpec(),
+                          pieces=pieces, cost_table=cost_table)
 
 
 def replan(
@@ -187,7 +167,6 @@ def replan(
     cluster: Cluster,
     input_size: tuple[int, int],
     prev: PicoPlan,
-    t_lim: float = _UNSET,
     cost_table: CostTable | None = None,
     spec: PlanSpec | None = None,
     planner_cache: PlannerCache | None = None,
@@ -203,15 +182,7 @@ def replan(
     observed/modeled EWMA — so successive re-plans optimize against the
     cluster as it behaves, not as it was specced.
     """
-    if spec is not None:
-        if not unset(t_lim):
-            raise TypeError("pass either spec= or t_lim=, not both")
-    else:
-        if not unset(t_lim):
-            warn_legacy("repro.core.replan",
-                        "replan(..., spec=PlanSpec(...))")
-        spec = PlanSpec(t_lim=pick(t_lim, float("inf")))
-    return plan_with_spec(g, cluster, input_size, spec,
+    return plan_with_spec(g, cluster, input_size, spec or PlanSpec(),
                           partition=prev.partition, cost_table=cost_table,
                           planner_cache=planner_cache)
 
@@ -284,7 +255,6 @@ def partition_cluster(
     models: Sequence,
     cluster: Cluster,
     weights: Sequence[float] | None = None,
-    t_lims: Sequence[float] | None = None,
     cost_table: CostTable | None = None,
     prev: Sequence[PicoPlan | None] | None = None,
     plan_specs: Sequence[PlanSpec | None] | None = None,
@@ -302,8 +272,7 @@ def partition_cluster(
     target.  ``prev[i]`` (a prior :class:`PicoPlan` for model ``i``)
     reuses Algorithm 1's piece chain so load-shift re-partitions only
     redo the device-dependent planning steps.  ``plan_specs[i]`` carries
-    tenant ``i``'s planner knobs; ``t_lims`` is the legacy equivalent
-    (ignored where a spec is given).
+    tenant ``i``'s planner knobs (None: ``PlanSpec()``).
 
     ``plan_fn(i, model, sub_cluster, spec, prev_plan) -> PicoPlan``
     overrides how each share is planned — the hook the serving scheduler
@@ -323,10 +292,8 @@ def partition_cluster(
     for i, bucket in enumerate(buckets):
         sub = cluster.restricted(bucket)
         m = models[i]
-        spec = plan_specs[i] if plan_specs is not None else None
-        if spec is None:
-            t_lim = t_lims[i] if t_lims is not None else float("inf")
-            spec = PlanSpec(t_lim=t_lim)
+        spec = (plan_specs[i] if plan_specs is not None else None) \
+            or PlanSpec()
         prev_i = prev[i] if prev is not None else None
         if plan_fn is not None:
             pico = plan_fn(i, m, sub, spec, prev_i)

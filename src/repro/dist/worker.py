@@ -94,22 +94,16 @@ class StageWorker:
         import jax
 
         from ..api.deployment import Deployment
-        from ..pipeline.stage import StageExecutor
+        from ..pipeline.stage import stage_executor
 
         p = self.payload
         # the artifact round-trip IS the hand-off: from_json re-applies
         # the exec-spec cache bound and installs the shipped CostTable's
         # autotuned kernel winners (per-worker executable warmup)
         dep = Deployment.from_json(p["deployment"])
-        st = dep.pico.pipeline.stages[self.stage_index]
-        spec = dep.exec_spec
-        # built exactly the way PipelineRunner builds its executors
-        # (backend/mode only), so the executable-cache key — and the
-        # numerics — match the single-process compiled path bit-for-bit
-        self.executor = StageExecutor(
-            dep.model, st.nodes, list(st.fractions),
-            name=f"stage{self.stage_index}", backend=spec.backend,
-            mode=spec.mode, tracer=self.tracer)
+        self.executor = stage_executor(
+            dep.model, dep.pico.pipeline.stages[self.stage_index],
+            self.stage_index, dep.exec_spec, tracer=self.tracer)
         self.upstream.tracer = self.downstream.tracer = self.tracer
         # stage i runs on local device i (mod the count): thread workers
         # of one process spread over the host's chips; committed params

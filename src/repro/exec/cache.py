@@ -140,14 +140,15 @@ def stage_cache_key(model, nodes, plans, needs, *, backend, donate,
 
 
 def compiled_stage(model, nodes, plans, needs: Sequence, sinks: Sequence,
-                   *, backend: str | None, donate: bool,
-                   boundary: Mapping, static_key: tuple | None = None,
+                   *, backend: str, boundary: Mapping,
+                   static_key: tuple | None = None,
                    fuse: bool = True, name: str = "stage") -> CompiledStage:
     """Fetch-or-build the executable for one stage + boundary shapes.
     ``name`` scopes the ops a miss lowers (``jax.named_scope``); it is
-    not part of the key, so identical stages share one executable."""
+    not part of the key, so identical stages share one executable.
+    Stages of a plan share boundary tensors, so none donates them."""
     key = stage_cache_key(model, nodes, plans, needs, backend=backend,
-                          donate=donate, boundary=boundary,
+                          donate=False, boundary=boundary,
                           static_key=static_key, fuse=fuse)
     hit = _CACHE.get(key)
     tr = obs_trace.current()
@@ -164,7 +165,7 @@ def compiled_stage(model, nodes, plans, needs: Sequence, sinks: Sequence,
                    hit=False)
     t0 = _time.perf_counter()
     cs = CompiledStage(model, nodes, plans, needs, sinks, backend=backend,
-                       donate=donate, fuse=fuse, name=name)
+                       fuse=fuse, name=name)
     default_registry().histogram("exec.compile.build_s").observe(
         _time.perf_counter() - t0)
     _CACHE[key] = cs

@@ -69,7 +69,7 @@ class CalibrationReport:
 
 
 def calibrate_plan(model, params, stages: Sequence, *,
-                   backend: str | None = None, image=None,
+                   spec=None, image=None,
                    iters: int = 3, host_flops: float | None = None,
                    key: int = 0) -> CalibrationReport:
     """Time every stage of a plan through its compiled executable.
@@ -77,10 +77,12 @@ def calibrate_plan(model, params, stages: Sequence, *,
     ``stages`` is the ``PicoPlan.pipeline.stages`` list (each entry
     carries nodes, fractions and the analytic SegmentCost).  Boundary
     tensors are produced by actually running the pipeline in plan order,
-    so each stage is timed on its real input shapes.  Returns a report
-    whose :meth:`~CalibrationReport.table` feeds the planner.
+    so each stage is timed on its real input shapes, through the
+    executor ``spec`` (:class:`~repro.api.specs.ExecSpec`, default
+    ``ExecSpec()``) builds for it on every other path.  Returns a
+    report whose :meth:`~CalibrationReport.table` feeds the planner.
     """
-    from ..pipeline.stage import StageExecutor     # lazy: avoid cycle
+    from ..pipeline.stage import stage_executor     # lazy: avoid cycle
     host_flops = host_flops or measure_host_flops()
     if image is None:
         w, h = model.input_size
@@ -91,8 +93,7 @@ def calibrate_plan(model, params, stages: Sequence, *,
     for si, st in enumerate(stages):
         with obs_trace.current().wall_span("calibrate", stage=si,
                                            n_nodes=len(st.nodes)):
-            ex = StageExecutor(model, st.nodes, list(st.fractions),
-                               name=f"calib{si}", backend=backend)
+            ex = stage_executor(model, st, si, spec, prefix="calib")
             outs = ex(params, produced, image)          # compile + warm
             jax.block_until_ready(outs)
             best = float("inf")
@@ -109,21 +110,19 @@ def calibrate_plan(model, params, stages: Sequence, *,
 
 
 def calibrated_plan(g, cluster, input_size, model, params, *,
-                    backend: str | None = None, t_lim: float = float("inf"),
-                    iters: int = 3, plan_spec=None):
+                    plan_spec=None, exec_spec=None, iters: int = 3):
     """Plan -> calibrate -> re-plan on measured costs (one closed loop).
 
     Returns ``(pico, report)`` where ``pico`` was re-planned with the
     measured :class:`CostTable` and ``report`` holds the raw timings.
-    ``plan_spec`` (:class:`~repro.api.specs.PlanSpec`) supersedes the
-    bare ``t_lim``.
+    ``plan_spec``/``exec_spec`` default to ``PlanSpec()``/``ExecSpec()``.
     """
     from ..api.specs import PlanSpec
     from ..core.planner import plan_with_spec
-    spec = plan_spec or PlanSpec(t_lim=t_lim)
+    spec = plan_spec or PlanSpec()
     first = plan_with_spec(g, cluster, input_size, spec)
     report = calibrate_plan(model, params, first.pipeline.stages,
-                            backend=backend, iters=iters)
+                            spec=exec_spec, iters=iters)
     table = report.table()
     return plan_with_spec(g, cluster, input_size, spec,
                           partition=first.partition,

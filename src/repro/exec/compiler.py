@@ -14,10 +14,10 @@ Two entry points per :class:`CompiledStage`:
   frame axis, micro-batched through ``lax.scan`` so the whole stream is
   one dispatch with constant memory in the number of frames.
 
-Buffer donation (``donate=True``) hands the boundary buffers to XLA for
-in-place reuse — safe only when the caller will not read them again
-(the scan/benchmark paths own their inputs; the multi-stage runner
-shares ``produced`` tensors across stages, so it keeps donation off).
+Buffer donation (``donate=True``, through :func:`compile_stage` only)
+hands the boundary buffers to XLA for in-place reuse — safe only when
+the caller will not read them again.  Stage executors never donate:
+the stages of a plan share boundary tensors.
 """
 
 from __future__ import annotations
@@ -29,7 +29,7 @@ import jax
 from ..obs.metrics import default_registry
 from ..pipeline.halo import (TilePlan, plan_tiles, split_inputs,
                              stitch_outputs)
-from .backends import DEFAULT_BACKEND, has_fused
+from .backends import has_fused
 
 
 def fusable_chains(graph, nodes) -> dict[str, str]:
@@ -93,7 +93,7 @@ class CompiledStage:
 
     def __init__(self, model, nodes, plans: Sequence[TilePlan],
                  needs: Sequence[tuple[str, str | None]],
-                 sinks: Sequence[str], *, backend: str | None = None,
+                 sinks: Sequence[str], *, backend: str,
                  donate: bool = False,
                  fuse: bool = True, name: str = "stage"):
         self.model = model
@@ -110,9 +110,8 @@ class CompiledStage:
         # backends with a fused lowering (xla keeps the composed-op
         # sequence and with it bit-equality vs the eager oracle)
         self.fuse = bool(fuse)
-        name = backend or getattr(model, "backend", None) or DEFAULT_BACKEND
         self.fusion = fusable_chains(model.graph, self.nodes) \
-            if self.fuse and has_fused(name) else {}
+            if self.fuse and has_fused(backend) else {}
         # XLA on CPU cannot alias donated buffers; donation there only
         # produces warnings, so honor the flag on accelerators only
         self.donate = bool(donate) and jax.default_backend() != "cpu"
@@ -164,17 +163,16 @@ class CompiledStage:
 
 def compile_stage(model, nodes, fractions: Sequence[float], *,
                   backend: str | None = None,
-                  donate: bool = False, fuse: bool = True,
-                  spec=None) -> CompiledStage:
-    """Convenience: plan tiles for ``fractions`` and compile the stage.
-    ``spec`` (:class:`~repro.api.specs.ExecSpec`) supersedes the
-    individual ``backend``/``donate``/``fuse`` knobs when given."""
-    if spec is not None:
-        backend, donate, fuse = spec.backend, spec.donate, spec.fuse
+                  donate: bool = False, fuse: bool = True) -> CompiledStage:
+    """Convenience: plan tiles for ``fractions`` and compile the stage
+    alone.  ``donate`` is for a caller that will not read the boundary
+    buffers again; ``backend`` None means the model's own, else the
+    registry default."""
+    from ..pipeline.stage import conv_backend
     nodes = frozenset(nodes)
     g = model.graph
     plans = plan_tiles(g, nodes, model.full_sizes, model.input_size,
                        list(fractions))
     return CompiledStage(model, nodes, plans, model.boundary_needs(nodes),
-                         g.sinks(nodes), backend=backend,
+                         g.sinks(nodes), backend=conv_backend(model, backend),
                          donate=donate, fuse=fuse)

@@ -198,25 +198,6 @@ class CNNDef:
         return outs
 
 
-def set_conv_backend(name: str):
-    """Deprecated: set ``CNNDef.backend`` (or pass ``backend=`` to the
-    executors) instead of flipping a process-wide default.
-
-    Unlike the seed's module global (read at apply time), this only
-    changes the *default* for executors built afterwards — a
-    StageExecutor resolves its backend once at construction, so
-    already-built executors keep the numerics they were created with.
-    """
-    import warnings
-    from ...exec import backends as _backends
-    warnings.warn("set_conv_backend is deprecated; set CNNDef.backend or "
-                  "pass backend= to StageExecutor/PipelineRunner "
-                  "(executors built before this call keep their backend)",
-                  DeprecationWarning, stacklevel=2)
-    assert name in _backends.available_backends(), name
-    _backends.DEFAULT_BACKEND = name
-
-
 # ---------------------------------------------------------------------------
 # builder helpers
 # ---------------------------------------------------------------------------

@@ -9,13 +9,12 @@ Two execution modes:
   ``shard_map`` + ``lax.ppermute`` over a dedicated mesh axis: the form
   PICO takes on a real TPU mesh, where each stage lives on its own
   slice of the ``stage`` (or ``pod``) axis and microbatches stream
-  through (DESIGN.md §5).  Works on any mesh whose ``stage`` axis size
+  through.  Works on any mesh whose ``stage`` axis size
   equals the number of pipeline stages.
 """
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Callable, Mapping, Sequence
 
@@ -25,28 +24,20 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..api.specs import ExecSpec
 from ..core.pipeline_dp import PipelinePlan
-from .stage import StageExecutor, executors_from_plan
+from .stage import executors_from_plan
 
 
 @dataclass
 class PipelineRunner:
     model: "CNNDef"                  # noqa: F821 (models.cnn.builder)
     plan: PipelinePlan
-    backend: str | None = None       # conv lowering; None -> model default
-    mode: str = "compiled"           # "compiled" | "eager" stage execution
-    exec_spec: object = None         # ExecSpec; supersedes backend/mode
+    exec_spec: ExecSpec | None = None   # None -> ExecSpec()
 
     def __post_init__(self):
-        if self.exec_spec is not None:
-            # donate is deliberately NOT taken from the spec: stages here
-            # share `produced` boundary tensors across the whole plan, so
-            # donation would let XLA clobber buffers later stages read
-            self.backend = self.exec_spec.backend
-            self.mode = self.exec_spec.mode
         self.stages = executors_from_plan(self.model, self.plan.stages,
-                                          backend=self.backend,
-                                          mode=self.mode)
+                                          self.exec_spec)
 
     def __call__(self, params, image: jax.Array) -> dict[str, jax.Array]:
         produced: dict[str, jax.Array] = {}

@@ -12,6 +12,12 @@ Every call records a ``stage`` span (``stage`` attribute: the
 executor's name) around the stage's dispatch — into ``tracer`` when the
 owner hands one over (a dist worker), else into the active tracer —
 which also names the call in JAX profiler traces.
+
+:func:`stage_executor` is the one place an
+:class:`~repro.api.specs.ExecSpec` and a planned stage become an
+executor: the runner, the runtime, the dist workers, the calibrator
+and the servers all build their stages through it, so one spec gives
+one executable (and one executable-cache key) on every path.
 """
 
 from __future__ import annotations
@@ -22,6 +28,7 @@ from typing import Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..api.specs import ExecSpec
 from ..core.pipeline_dp import StagePlan
 from ..obs import trace as obs_trace
 from .halo import TilePlan, plan_tiles, split_inputs, stitch_outputs
@@ -37,8 +44,6 @@ class StageExecutor:
     name: str = "stage"
     backend: str | None = None       # None -> model.backend -> registry default
     mode: str = "compiled"           # "compiled" | "eager"
-    donate: bool = False             # donate boundary buffers to XLA — only
-    #                                  safe when the caller won't reuse them
     fuse: bool = True                # lower conv->pool chains as one fused
     #                                  kernel call (compiled mode, backends
     #                                  with a fused lowering only)
@@ -53,9 +58,7 @@ class StageExecutor:
             self.fractions)
         # (node, outside_pred) pairs fed across the stage boundary
         self.needs = self.model.boundary_needs(self.nodes)
-        if self.backend is None:
-            from ..exec import backends as _backends
-            self.backend = self.model.backend or _backends.DEFAULT_BACKEND
+        self.backend = conv_backend(self.model, self.backend)
         if self.mode not in ("compiled", "eager"):
             raise ValueError(f"unknown mode {self.mode!r}")
         # per-call-invariant part of the executable-cache key, computed
@@ -106,7 +109,6 @@ class StageExecutor:
         from ..exec.cache import compiled_stage
         return compiled_stage(self.model, self.nodes, self.plans,
                               self.needs, self.sinks, backend=self.backend,
-                              donate=self.donate,
                               boundary=boundary, static_key=self._static_key,
                               fuse=self.fuse, name=self.name)
 
@@ -125,21 +127,27 @@ class StageExecutor:
         return stitch_outputs(self.plans, self.sinks, tiles_out)
 
 
+def conv_backend(model, backend: str | None = None) -> str:
+    """The conv lowering a stage of ``model`` runs: ``backend``, else
+    the model's own, else the registry default."""
+    from ..exec import backends
+    return backend or model.backend or backends.DEFAULT_BACKEND
+
+
+def stage_executor(model: "CNNDef", stage: StagePlan, index: int,  # noqa: F821
+                   spec: ExecSpec | None = None, *, tracer=None,
+                   prefix: str = "stage") -> StageExecutor:
+    """The executor of stage ``index`` of a plan under ``spec``
+    (default :class:`ExecSpec`), named ``<prefix><index>``; ``tracer``
+    takes its ``stage`` spans (None: the active tracer)."""
+    spec = spec or ExecSpec()
+    return StageExecutor(model, stage.nodes, list(stage.fractions),
+                         name=f"{prefix}{index}", backend=spec.backend,
+                         mode=spec.mode, tracer=tracer)
+
+
 def executors_from_plan(model: "CNNDef", stages: Sequence[StagePlan],  # noqa: F821
-                        backend: str | None = None, mode: str = "compiled",
-                        donate: bool = False,
-                        spec=None) -> list[StageExecutor]:
-    """Build one executor per stage.  ``spec``
-    (:class:`~repro.api.specs.ExecSpec`) supersedes the individual
-    ``backend``/``mode`` knobs when given — but never ``donate``:
-    stages of one plan share boundary tensors, so donation here would
-    let XLA clobber buffers a later stage still reads (single-stage
-    callers opt in via the explicit ``donate=`` argument)."""
-    fuse = True
-    if spec is not None:
-        backend, mode = spec.backend, spec.mode
-        fuse = getattr(spec, "fuse", True)
-    return [StageExecutor(model, st.nodes, list(st.fractions),
-                          name=f"stage{si}", backend=backend, mode=mode,
-                          donate=donate, fuse=fuse)
-            for si, st in enumerate(stages)]
+                        spec: ExecSpec | None = None) -> list[StageExecutor]:
+    """One :func:`stage_executor` per stage of a plan."""
+    return [stage_executor(model, st, i, spec)
+            for i, st in enumerate(stages)]

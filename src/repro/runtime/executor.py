@@ -49,7 +49,6 @@ from typing import Sequence
 
 import numpy as np
 
-from ..api._compat import _UNSET, pick, unset, warn_legacy
 from ..api.specs import ExecSpec, PlanSpec
 from ..core.cost import Cluster, CostTable
 from ..core.pipeline_dp import PlannerCache, StagePlan
@@ -221,8 +220,6 @@ class PipelineRuntime:
         churn: Sequence[ChurnEvent] = (),
         model=None,                     # CNNDef: real JAX compute per stage
         params=None,
-        t_lim: float = _UNSET,          # deprecated: use plan_spec=
-        backend: str | None = _UNSET,   # deprecated: use exec_spec=
         cost_table: CostTable | None = None,  # measured costs (exec.calibrate)
         plan_spec: PlanSpec | None = None,
         exec_spec: ExecSpec | None = None,
@@ -235,19 +232,11 @@ class PipelineRuntime:
             input_size = model.input_size
         if g is None or cluster is None or input_size is None:
             raise ValueError("need (g, cluster, input_size) or model=")
-        if not unset(t_lim, backend):
-            if plan_spec is not None or exec_spec is not None:
-                raise TypeError("pass either specs or the legacy "
-                                "t_lim=/backend= kwargs, not both")
-            warn_legacy("repro.runtime.PipelineRuntime",
-                        "PipelineRuntime(..., plan_spec=PlanSpec(...), "
-                        "exec_spec=ExecSpec(...))")
         self.g = g
         self.input_size = input_size
         self.cluster = cluster
-        self.plan_spec = plan_spec or PlanSpec(t_lim=pick(t_lim,
-                                                          float("inf")))
-        self.exec_spec = exec_spec or ExecSpec(backend=pick(backend, None))
+        self.plan_spec = plan_spec or PlanSpec()
+        self.exec_spec = exec_spec or ExecSpec()
         self.model = model
         self.params = params
         self.cost_table = cost_table
@@ -282,14 +271,6 @@ class PipelineRuntime:
         self._samples_at_replan = 0
         self._build_stages()
 
-    @property
-    def t_lim(self) -> float:
-        return self.plan_spec.t_lim
-
-    @property
-    def backend(self) -> str | None:
-        return self.exec_spec.backend
-
     # ------------------------------------------------------------------
     # plan -> executable stage states
     # ------------------------------------------------------------------
@@ -302,7 +283,7 @@ class PipelineRuntime:
             # compiled executors: across re-plans, stages whose segment +
             # tiling survive come straight from the executable cache
             execs = executors_from_plan(self.model, self.pico.pipeline.stages,
-                                        spec=self.exec_spec)
+                                        self.exec_spec)
             for st, ex in zip(self.stages, execs):
                 st.executor = ex
 
@@ -551,9 +532,8 @@ class PipelineRuntime:
     def _exec_batch(self, st: _StageState, batch: "_Batch") -> None:
         """Real numerics for one batch: single frames keep the seed's
         bit-exact ``__call__`` path; larger batches stack the boundary
-        tensors and go through the compiled ``run_frames`` scan (unless
-        ``ExecSpec.scan_batch`` turned the scan path off)."""
-        if len(batch) == 1 or not self.exec_spec.scan_batch:
+        tensors and go through the compiled ``run_frames`` scan."""
+        if len(batch) == 1:
             for fr in batch.frames:
                 outs = st.executor(self.params, fr.produced, fr.image)
                 fr.produced.update(outs)

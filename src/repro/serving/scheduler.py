@@ -35,7 +35,6 @@ import zlib
 from dataclasses import dataclass, field, replace
 from typing import Mapping, Sequence
 
-from ..api._compat import _UNSET, pick, unset, warn_legacy
 from ..api.specs import ExecSpec, PlanSpec
 from ..core.cost import Cluster, CostTable
 from ..core.pipeline_dp import PlannerCache
@@ -64,11 +63,10 @@ class TenantConfig:
     slo_s: float = float("inf")     # per-request deadline after arrival
     max_queue: int = 256            # admission bound on in-system requests
     max_batch: int = 4              # stage-0 micro-batch cap
-    t_lim: float = float("inf")     # planner latency limit (legacy surface)
-    plan_spec: PlanSpec | None = None   # full planner spec; wins over t_lim
+    plan_spec: PlanSpec | None = None   # None -> PlanSpec()
 
     def planner_spec(self) -> PlanSpec:
-        return self.plan_spec or PlanSpec(t_lim=self.t_lim)
+        return self.plan_spec or PlanSpec()
 
 
 @dataclass
@@ -204,7 +202,6 @@ class ServingScheduler:
 
     def __init__(self, tenants: Sequence[TenantConfig], cluster: Cluster,
                  config: SchedulerConfig | None = None,
-                 backend: str | None = _UNSET,
                  cost_table: CostTable | None = None,
                  exec_spec: ExecSpec | None = None,
                  registry=None):
@@ -213,15 +210,9 @@ class ServingScheduler:
             raise ValueError(f"duplicate tenant names in {names}")
         if not tenants:
             raise ValueError("need at least one tenant")
-        if not unset(backend):
-            if exec_spec is not None:
-                raise TypeError("pass either exec_spec= or the legacy "
-                                "backend= kwarg, not both")
-            warn_legacy("repro.serving.ServingScheduler",
-                        "ServingScheduler(..., exec_spec=ExecSpec(...))")
         self.cluster = cluster
         self.config = config or SchedulerConfig()
-        self.exec_spec = exec_spec or ExecSpec(backend=pick(backend, None))
+        self.exec_spec = exec_spec or ExecSpec()
         self.cost_table = cost_table
         # optional fleet PlanRegistry: repartitions consult it before
         # planning, and publish fresh plans back for the rest of the

@@ -16,30 +16,12 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..api._compat import _UNSET, pick, unset, warn_legacy
 from ..api.specs import DeploySpec, ExecSpec, PlanSpec
 from ..core import Cluster, plan_with_spec
 from ..obs.metrics import quantile
 from ..models.cnn.builder import CNNDef
 from ..pipeline.runner import PipelineRunner
 from ..data.pipeline import Request
-
-
-def _resolve_specs(who: str, t_lim, backend, plan_spec, exec_spec
-                   ) -> tuple["PlanSpec", "ExecSpec"]:
-    """Map a server's legacy ``t_lim=``/``backend=`` kwargs onto specs,
-    warning once per entry point; reject mixing the two surfaces."""
-    if not unset(t_lim, backend):
-        if plan_spec is not None or exec_spec is not None:
-            raise TypeError(f"{who}: pass either specs or the legacy "
-                            "t_lim=/backend= kwargs, not both")
-        # one extra frame (this helper) between warn and the user's call
-        warn_legacy(who, f"{who}(..., plan_spec=PlanSpec(...), "
-                         "exec_spec=ExecSpec(...)) or repro.compile()",
-                    stacklevel=4)
-    plan_spec = plan_spec or PlanSpec(t_lim=pick(t_lim, float("inf")))
-    exec_spec = exec_spec or ExecSpec(backend=pick(backend, None))
-    return plan_spec, exec_spec
 
 
 def _load_params_idempotent(srv, key):
@@ -150,21 +132,17 @@ class ServeStats:
 
 class PipelineServer:
     def __init__(self, model: CNNDef, cluster: Cluster,
-                 t_lim: float = _UNSET, backend: str | None = _UNSET,
                  cost_table=None, plan_spec: PlanSpec | None = None,
                  exec_spec: ExecSpec | None = None, pico=None):
-        plan_spec, exec_spec = _resolve_specs(
-            "repro.serving.PipelineServer", t_lim, backend,
-            plan_spec, exec_spec)
         self.model = model
         self.cluster = cluster
-        self.exec_spec = exec_spec
+        self.exec_spec = exec_spec or ExecSpec()
         self.pico = pico or plan_with_spec(model.graph, cluster,
-                                           model.input_size, plan_spec,
+                                           model.input_size,
+                                           plan_spec or PlanSpec(),
                                            cost_table=cost_table)
         self.runner = PipelineRunner(model, self.pico.pipeline,
-                                     backend=exec_spec.backend,
-                                     mode=exec_spec.mode)
+                                     self.exec_spec)
         self.params = None
 
     def load(self, key=None):
@@ -214,15 +192,11 @@ class StreamingPipelineServer:
     """
 
     def __init__(self, model: CNNDef, cluster: Cluster,
-                 t_lim: float = _UNSET, config=None, churn=(),
-                 backend: str | None = _UNSET, cost_table=None,
+                 config=None, churn=(), cost_table=None,
                  plan_spec: PlanSpec | None = None,
                  exec_spec: ExecSpec | None = None,
                  deploy_spec: DeploySpec | None = None, pico=None):
         from ..runtime import RuntimeConfig
-        plan_spec, exec_spec = _resolve_specs(
-            "repro.serving.StreamingPipelineServer", t_lim, backend,
-            plan_spec, exec_spec)
         if deploy_spec is not None and config is not None:
             raise TypeError("pass either deploy_spec= or config=, not both")
         if deploy_spec is not None:

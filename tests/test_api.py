@@ -1,13 +1,13 @@
 """The public ``repro.api`` facade: specs, compile() -> Deployment,
-artifact serialization, and the legacy-kwarg deprecation shims.
+artifact serialization, and the one stage-building seam.
 
 Pins the contract of the API redesign: the facade produces plans
-identical to the legacy entry points, every artifact JSON round-trips
-exactly, and a saved deployment reloads with zero re-planning or
-re-calibration while behaving bit-identically."""
+identical to ``core.plan``, every artifact JSON round-trips exactly, a
+saved deployment reloads with zero re-planning or re-calibration while
+behaving bit-identically, and the runner, the runtime and a dist
+worker build the same executors from one spec."""
 
 import json
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -15,14 +15,11 @@ import numpy as np
 import pytest
 
 import repro
-from repro.api import (DeploySpec, ExecSpec, PlanSpec, artifacts,
-                       reset_legacy_warnings)
+from repro.api import DeploySpec, ExecSpec, PlanSpec, artifacts
 from repro.api.deployment import FEED_CHUNK, feed_chunks, stack_frames
 from repro.core import (CostTable, make_pi_cluster, plan, replan, simulate)
 from repro.core.partition import PartitionResult
 from repro.models.cnn import zoo
-from repro.serving import PipelineServer
-from repro.runtime import PipelineRuntime
 
 
 def _tiny(name, size=64, scale=0.25):
@@ -60,7 +57,7 @@ def test_spec_validation():
 
 @pytest.mark.parametrize("spec", [
     PlanSpec(), PlanSpec(t_lim=0.25, max_diameter=3, n_split=4),
-    ExecSpec(), ExecSpec(backend="xla", mode="eager", donate=True,
+    ExecSpec(), ExecSpec(backend="xla", mode="eager",
                          cache_size=8, calibrate=True, calibrate_iters=2),
     DeploySpec(), DeploySpec(seed=3, max_batch=4, compute_noise=0.1,
                              migration_bandwidth=1e9),
@@ -102,39 +99,19 @@ def test_deploy_spec_maps_to_runtime_config():
 
 
 # ---------------------------------------------------------------------------
-# facade vs legacy equivalence (the model zoo)
+# facade vs core.plan equivalence (the model zoo)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("name,scale", [
     ("vgg16", 0.125), ("squeezenet", 0.25), ("mobilenetv3", 0.25),
     ("resnet34", 0.125), ("inceptionv3", 0.25),
 ])
-def test_compile_matches_legacy_plan(name, scale):
+def test_compile_matches_core_plan(name, scale):
     m = _tiny(name, scale=scale)
     cluster = make_pi_cluster([1.5, 1.0, 0.8])
-    legacy = plan(m.graph, cluster, m.input_size)
+    direct = plan(m.graph, cluster, m.input_size)
     dep = repro.compile(m, cluster)
-    assert _canon(dep.pico) == _canon(legacy)
-
-
-def test_compile_spec_knobs_equal_legacy_kwargs():
-    m = _tiny("squeezenet")
-    cluster = make_pi_cluster([1.5, 1.2, 1.0])
-    reset_legacy_warnings()
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy = plan(m.graph, cluster, m.input_size, t_lim=0.02,
-                      max_diameter=3, n_split=4)
-    dep = repro.compile(m, cluster,
-                        PlanSpec(t_lim=0.02, max_diameter=3, n_split=4))
-    assert _canon(dep.pico) == _canon(legacy)
-
-
-def test_plan_rejects_spec_plus_legacy_kwargs():
-    m = _tiny("squeezenet")
-    cluster = make_pi_cluster([1.5, 1.0])
-    with pytest.raises(TypeError):
-        plan(m.graph, cluster, m.input_size, t_lim=0.5, spec=PlanSpec())
+    assert _canon(dep.pico) == _canon(direct)
 
 
 # ---------------------------------------------------------------------------
@@ -321,6 +298,41 @@ def test_server_load_keeps_deployment_params():
     assert srv2.params is not dep.params
 
 
+def test_runner_runtime_server_and_dist_worker_build_equal_executors():
+    """One spec, one executor per stage, whichever path builds it."""
+    from repro.dist.transport import memory_pair
+    from repro.dist.worker import StageWorker, build_payload
+    m = _tiny("squeezenet", size=48)
+    spec = ExecSpec(backend="pallas", mode="eager")   # not the defaults
+    dep = repro.compile(m, make_pi_cluster([1.5, 1.0]),
+                        exec_spec=spec).load_params()
+    n = len(dep.pico.pipeline.stages)
+    assert n == 2
+
+    def built(ex):
+        return ex.name, ex.backend, ex.mode, ex.fuse, ex._static_key
+
+    def worker(i):
+        # as DistLauncher._start_threads builds a thread worker
+        payload = build_payload(
+            dep.to_json(), i, worker=f"w{i}", devices=["d0"], recv_nodes=[],
+            recv_image=True, forward=[], forward_image=False,
+            last=i == n - 1, seed=0, heartbeat_s=0.05, start_timeout_s=1.0,
+            chunk_bytes=1 << 20, epoch=0.0, trace=False)
+        (_, up), (down, _) = memory_pair("in"), memory_pair("out")
+        ctrl, _ = memory_pair("ctrl")
+        sw = StageWorker(json.loads(json.dumps(payload)), up, down, ctrl)
+        sw._setup()
+        return sw.executor
+
+    runner = [built(ex) for ex in dep.runner.stages]
+    assert [r[:4] for r in runner] == [
+        (f"stage{i}", "pallas", "eager", True) for i in range(n)]
+    assert [built(st.executor) for st in dep.runtime().stages] == runner
+    assert [built(ex) for ex in dep.server().runner.stages] == runner
+    assert [built(worker(i)) for i in range(n)] == runner
+
+
 def test_run_scan_batch_matches_per_frame():
     m = _tiny("squeezenet", size=48)
     cluster = make_pi_cluster([1.5, 1.0])
@@ -329,10 +341,7 @@ def test_run_scan_batch_matches_per_frame():
     dep = repro.compile(m, cluster)
     scanned = dep.run(xs)
     assert len(scanned) == 3
-    looped = repro.compile(
-        m, cluster, exec_spec=ExecSpec(scan_batch=False))
-    looped.params = dep.params
-    plain = looped.run(xs)
+    plain = [dep.run(x) for x in xs]        # single arrays: per frame
     for a, b in zip(scanned, plain):
         assert a.keys() == b.keys()
         for k in a:
@@ -341,15 +350,11 @@ def test_run_scan_batch_matches_per_frame():
 
 
 @pytest.fixture(scope="module")
-def scan_pair():
-    """A scanning deployment and its per-frame twin on the same weights."""
+def scan_dep():
+    """A two-stage deployment with its weights loaded."""
     m = _tiny("squeezenet", size=48)
     cluster = make_pi_cluster([1.5, 1.0])
-    dep = repro.compile(m, cluster).load_params()
-    looped = repro.compile(m, cluster,
-                           exec_spec=ExecSpec(scan_batch=False))
-    looped.params = dep.params
-    return dep, looped
+    return repro.compile(m, cluster).load_params()
 
 
 def _host_frames(n, dtype=np.float32, shape=(1, 48, 48, 3)):
@@ -358,12 +363,12 @@ def _host_frames(n, dtype=np.float32, shape=(1, 48, 48, 3)):
 
 
 @pytest.mark.parametrize("kind", ["numpy", "jax"])
-def test_run_scan_frames_bit_exact_with_per_frame(scan_pair, kind):
-    dep, looped = scan_pair
+def test_run_scan_frames_bit_exact_with_per_frame(scan_dep, kind):
+    dep = scan_dep
     host = _host_frames(3)
     frames = host if kind == "numpy" else [jnp.asarray(x) for x in host]
     outs = dep.run(frames)
-    plain = looped.run(host)
+    plain = [dep.run(x) for x in host]      # single arrays: per frame
     assert len(outs) == 3
     for a, b in zip(outs, plain):
         assert list(a) == list(b)
@@ -375,30 +380,27 @@ def test_run_scan_frames_bit_exact_with_per_frame(scan_pair, kind):
 
 
 @pytest.fixture(scope="module", params=[1, 2], ids=["1-stage", "2-stage"])
-def chunk_pair(request):
-    """A scanning deployment of ``request.param`` stages and its
-    per-frame twin on the same weights."""
+def chunk_dep(request):
+    """A deployment of ``request.param`` stages with its weights
+    loaded."""
     m = _tiny("squeezenet", size=48)
     cluster = make_pi_cluster([1.5, 1.0][:request.param])
     dep = repro.compile(m, cluster).load_params()
-    looped = repro.compile(m, cluster,
-                           exec_spec=ExecSpec(scan_batch=False))
-    looped.params = dep.params
     assert len(dep.pico.pipeline.stages) == request.param
-    return dep, looped
+    return dep
 
 
 @pytest.mark.parametrize("sizes", [
     [FEED_CHUNK], [FEED_CHUNK] * 2, [FEED_CHUNK, 3], [FEED_CHUNK] * 2 + [1]],
     ids=["one-chunk", "even", "ragged", "ragged-1"])
-def test_run_chunked_host_feed_bit_exact(chunk_pair, sizes):
-    dep, looped = chunk_pair
+def test_run_chunked_host_feed_bit_exact(chunk_dep, sizes):
+    dep = chunk_dep
     n = sum(sizes)
     host = _host_frames(n)
     assert [len(c) for c in feed_chunks(host)] == sizes
     fed = dep.run(host)
     one_stack = dep.run([jnp.asarray(x) for x in host])
-    plain = looped.run(host)
+    plain = [dep.run(x) for x in host]      # single arrays: per frame
     assert len(fed) == len(one_stack) == len(plain) == n
     for a, b, c in zip(fed, one_stack, plain):
         assert list(a) == list(b) == list(c)
@@ -421,8 +423,8 @@ def test_feed_chunks_one_chunk_unless_uniform_host_frames():
         assert only is odd
 
 
-def test_run_float64_frames_canonicalised_as_jnp_stack(scan_pair):
-    dep, _ = scan_pair
+def test_run_float64_frames_canonicalised_as_jnp_stack(scan_dep):
+    dep = scan_dep
     f64 = _host_frames(3, np.float64)
     assert jnp.stack(f64).dtype == jnp.float32
     got = dep.run(f64)
@@ -459,80 +461,6 @@ def test_stack_frames_keeps_jnp_stack_dtype(case, src):
     assert how == src and isinstance(got, jax.Array)
     assert got.dtype == want.dtype and got.weak_type == want.weak_type
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-# ---------------------------------------------------------------------------
-# deprecation shims: warn exactly once, bit-identical results
-# ---------------------------------------------------------------------------
-
-def _one_deprecation(wlist):
-    return [w for w in wlist if issubclass(w.category, DeprecationWarning)]
-
-
-def test_plan_legacy_kwargs_warn_exactly_once():
-    m = _tiny("squeezenet")
-    cluster = make_pi_cluster([1.5, 1.0])
-    reset_legacy_warnings()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        legacy = plan(m.graph, cluster, m.input_size, t_lim=0.05)
-        legacy2 = plan(m.graph, cluster, m.input_size, t_lim=0.05)
-    assert len(_one_deprecation(w)) == 1
-    spec_plan = plan(m.graph, cluster, m.input_size,
-                     spec=PlanSpec(t_lim=0.05))
-    assert _canon(legacy) == _canon(spec_plan) == _canon(legacy2)
-
-
-def test_replan_legacy_t_lim_warns_once():
-    m = _tiny("squeezenet")
-    cluster = make_pi_cluster([1.5, 1.0])
-    prev = plan(m.graph, cluster, m.input_size)
-    reset_legacy_warnings()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        a = replan(m.graph, cluster, m.input_size, prev=prev, t_lim=0.05)
-        replan(m.graph, cluster, m.input_size, prev=prev, t_lim=0.05)
-    assert len(_one_deprecation(w)) == 1
-    b = replan(m.graph, cluster, m.input_size, prev=prev,
-               spec=PlanSpec(t_lim=0.05))
-    assert _canon(a) == _canon(b)
-
-
-def test_pipeline_server_legacy_kwargs_warn_once_and_match():
-    m = _tiny("squeezenet")
-    cluster = make_pi_cluster([1.5, 1.0])
-    reset_legacy_warnings()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        legacy = PipelineServer(m, cluster, t_lim=0.05)
-        PipelineServer(m, cluster, t_lim=0.05)
-    assert len(_one_deprecation(w)) == 1
-    fresh = PipelineServer(m, cluster, plan_spec=PlanSpec(t_lim=0.05))
-    assert _canon(legacy.pico) == _canon(fresh.pico)
-
-
-def test_pipeline_runtime_legacy_kwargs_warn_once_and_match():
-    m = _tiny("squeezenet")
-    cluster = make_pi_cluster([1.5, 1.0])
-    reset_legacy_warnings()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        rt1 = PipelineRuntime(m.graph, cluster, m.input_size, t_lim=0.05)
-        rt2 = PipelineRuntime(m.graph, cluster, m.input_size, t_lim=0.05)
-    assert len(_one_deprecation(w)) == 1
-    rt3 = PipelineRuntime(m.graph, cluster, m.input_size,
-                          plan_spec=PlanSpec(t_lim=0.05))
-    assert _canon(rt1.pico) == _canon(rt2.pico) == _canon(rt3.pico)
-
-
-def test_mixing_spec_and_legacy_kwargs_raises():
-    m = _tiny("squeezenet")
-    cluster = make_pi_cluster([1.5, 1.0])
-    with pytest.raises(TypeError):
-        PipelineRuntime(m.graph, cluster, m.input_size, t_lim=0.05,
-                        plan_spec=PlanSpec())
-    with pytest.raises(TypeError):
-        PipelineServer(m, cluster, backend="xla", exec_spec=ExecSpec())
 
 
 # ---------------------------------------------------------------------------
@@ -594,19 +522,6 @@ def test_scheduler_exec_spec_and_tenant_plan_spec():
                 "b": [Request(i, 0.01 * i, None) for i in range(4)]}
     report = sched.serve(workload)
     assert report.served == 8 and report.dropped_inflight == 0
-
-
-def test_scheduler_legacy_backend_kwarg_warns_once():
-    from repro.serving import ServingScheduler, TenantConfig
-    cluster = make_pi_cluster([1.5, 1.0])
-    tenants = [TenantConfig(
-        "a", zoo.squeezenet(input_size=(64, 64), scale=0.1))]
-    reset_legacy_warnings()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        ServingScheduler(tenants, cluster, backend=None)
-        ServingScheduler(tenants, cluster, backend=None)
-    assert len(_one_deprecation(w)) == 1
 
 
 # ---------------------------------------------------------------------------

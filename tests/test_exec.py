@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro import exec as rexec
+from repro.api import ExecSpec
 from repro.core import CostTable, make_pi_cluster, plan, recost, replan
 from repro.models.cnn import zoo
 from repro.models.cnn.builder import GB
@@ -140,8 +141,8 @@ def test_run_frames_matches_per_frame_calls():
             np.testing.assert_array_equal(np.asarray(stacked[k][f]),
                                           np.asarray(v))
     # the eager oracle honors run_frames too (loops + stacks)
-    eager_stacked = PipelineRunner(m, p.pipeline,
-                                   mode="eager").run_frames(params, frames)
+    eager_stacked = PipelineRunner(m, p.pipeline, ExecSpec(mode="eager")
+                                   ).run_frames(params, frames)
     for k in stacked:
         np.testing.assert_array_equal(np.asarray(stacked[k]),
                                       np.asarray(eager_stacked[k]))

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings
 
+from repro.api import ExecSpec
 from repro.core import make_pi_cluster, plan
 from repro.models.cnn import zoo
 from repro.models.cnn.builder import GB
@@ -142,7 +143,7 @@ def test_compiled_multi_stage_plan_bit_exact_with_eager():
     params = m.init(jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 96, 96, 3))
     out_c = PipelineRunner(m, p.pipeline)(params, x)
-    out_e = PipelineRunner(m, p.pipeline, mode="eager")(params, x)
+    out_e = PipelineRunner(m, p.pipeline, ExecSpec(mode="eager"))(params, x)
     for k in out_c:
         np.testing.assert_array_equal(np.asarray(out_c[k]),
                                       np.asarray(out_e[k]))

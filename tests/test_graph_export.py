@@ -5,6 +5,7 @@ planner must build balanced pipelines for the assigned archs."""
 import pytest
 
 from repro import configs
+from repro.api import PlanSpec
 from repro.core import make_tpu_cluster, partition_graph, plan
 from repro.models.graph_export import export_graph
 
@@ -28,7 +29,7 @@ def test_planner_balances_decoder_pipeline(name):
     cfg = configs.get(name)
     g = export_graph(cfg, seq_len=1024)
     cluster = make_tpu_cluster(4)
-    p = plan(g, cluster, (1024, 1), max_diameter=2)
+    p = plan(g, cluster, (1024, 1), spec=PlanSpec(max_diameter=2))
     assert len(p.pipeline.stages) >= 2
     times = [st.cost.total for st in p.pipeline.stages]
     assert max(times) <= 2.5 * (sum(times) / len(times))  # balanced

@@ -282,7 +282,7 @@ def test_conv_kernel_integrates_with_cnn_zoo():
     """The Pallas conv kernel drops into the executable zoo and the
     pipelined stage executor unchanged (system <-> kernel integration).
     The backend is selected explicitly per model/executor — no module
-    global (the seed's `set_conv_backend` is deprecated)."""
+    global."""
     from repro.models.cnn import zoo
     from repro.pipeline.stage import StageExecutor
     m = zoo.vgg16(input_size=(40, 40), scale=0.1, head=False)

@@ -235,15 +235,23 @@ def test_profiler_sees_repro_annotations(dep2, tmp_path):
             "repro.stage.compute"} <= names
 
 
-def test_exec_spec_profile_field_retired():
-    old = dict(ExecSpec().to_dict(), profile=True)
+#: ExecSpec fields that were retired, each with a value it once took
+#: other than its default
+RETIRED = {"profile": True, "scan_batch": False, "fuse": False,
+           "donate": True}
+
+
+@pytest.mark.parametrize("field", sorted(RETIRED))
+def test_exec_spec_profile_field_retired(field):
+    old = dict(ExecSpec().to_dict(), **{field: RETIRED[field]})
     assert ExecSpec.from_dict(old) == ExecSpec()
-    assert "profile" not in ExecSpec().to_dict()
+    assert field not in ExecSpec().to_dict()
 
 
-def test_artifact_carrying_profile_loads(dep2):
+@pytest.mark.parametrize("field", sorted(RETIRED))
+def test_artifact_carrying_profile_loads(dep2, field):
     doc = json.loads(dep2.to_json())
-    doc["payload"]["exec_spec"]["profile"] = True
+    doc["payload"]["exec_spec"][field] = RETIRED[field]
     back = repro.Deployment.from_json(json.dumps(doc), model=dep2.model)
     assert back.exec_spec == dep2.exec_spec
 
