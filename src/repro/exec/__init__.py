@@ -4,10 +4,12 @@ The execution layer between the planner's :class:`StagePlan` and JAX:
 
 * :mod:`~repro.exec.backends` — pluggable conv backends (``xla``,
   ``pallas``) selected per model/executor, no mutable module global;
-  backends may register a *fused* conv-epilogue lowering;
+  backends may register a *fused* conv-epilogue lowering and the
+  chains it takes (xla: lane-sparse conv->pool chains on a TPU, in
+  space-to-depth phase form);
 * :mod:`~repro.exec.compiler` — lowers one stage's fused segment (all
   device tiles) into a single jitted callable, pattern-matching
-  conv->pool chains into single fused kernel calls, with optional
+  conv->pool chains into single fused calls, with optional
   buffer donation and ``lax.scan`` micro-batching over frames;
 * :mod:`~repro.exec.cache` — executable cache keyed on (segment
   signature, tile shapes, dtype, backend, fuse);
@@ -19,7 +21,7 @@ The execution layer between the planner's :class:`StagePlan` and JAX:
 """
 
 from .backends import (apply_conv, apply_layer, available_backends,
-                       default_interpret, get_backend, has_fused,
+                       default_interpret, fuses, get_backend,
                        register_backend)
 from .compiler import (CompiledStage, compile_stage, fusable_chains,
                        segment_signature)
@@ -33,7 +35,7 @@ from .autotune import (DEFAULT_CANDIDATES, TuneResult, autotune_conv,
 
 __all__ = [
     "apply_conv", "apply_layer", "available_backends", "default_interpret",
-    "get_backend", "has_fused", "register_backend", "CompiledStage",
+    "fuses", "get_backend", "register_backend", "CompiledStage",
     "compile_stage", "fusable_chains", "segment_signature", "CacheStats",
     "cache_stats", "clear_cache", "compiled_stage", "set_cache_size",
     "stage_cache_key", "static_stage_key",

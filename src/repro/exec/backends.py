@@ -11,6 +11,12 @@ Registered backends:
 
 ``xla``
     ``lax.conv_general_dilated`` — the reference path on every platform.
+    On a platform with 128-lane vector tiles (TPU) its fused lowering,
+    :func:`conv_pool_phase`, takes the conv -> 2x2/2 pool chains of
+    stride-1 convs whose space-to-depth input fits one lane tile
+    (``4 * Cin <= 128``): they run in space-to-depth phase form, the
+    pool a max over the phase groups.  Every other conv, and every
+    chain elsewhere, is the composed eager sequence.
 ``pallas``
     The repro's implicit-GEMM Pallas kernel (``kernels.conv2d``), which
     handles any stride >= 1 and any channel count (tails are padded up
@@ -25,13 +31,16 @@ Registered backends:
 
 A backend may additionally register a *fused* lowering: the signature
 covers the whole conv epilogue (conv + bias + activation + optional pool) in
-one call, and ``exec.compiler.fusable_chains`` only rewrites segments
-for backends that have one — backends without it (xla) keep the exact
-composed-op sequence, preserving bit-equality with the eager oracle.
+one call.  Beside it the backend registers which (conv, pool) pairs the
+fused lowering takes (:func:`fuses`; by default all of them), and
+``exec.compiler.CompiledStage`` fuses only those chains: a conv or chain
+it does not take keeps the exact composed-op sequence, preserving
+bit-equality with the eager oracle.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -50,16 +59,22 @@ FusedConvFn = Callable[
     [LayerSpec, Optional[LayerSpec], dict, jax.Array, tuple[int, int]],
     jax.Array]
 
+# which (conv_spec, pool_spec | None) the fused lowering takes
+EngageFn = Callable[[LayerSpec, Optional[LayerSpec]], bool]
+
 _REGISTRY: dict[str, ConvFn] = {}
-_FUSED: dict[str, FusedConvFn] = {}
+_FUSED: dict[str, tuple[FusedConvFn, EngageFn]] = {}
 DEFAULT_BACKEND = "xla"
 
 
 def register_backend(name: str, fn: ConvFn,
-                     fused: FusedConvFn | None = None) -> None:
+                     fused: FusedConvFn | None = None,
+                     engages: EngageFn | None = None) -> None:
+    """``engages`` picks the (conv, pool) pairs ``fused`` takes; None
+    means all of them, unpooled convs included."""
     _REGISTRY[name] = fn
     if fused is not None:
-        _FUSED[name] = fused
+        _FUSED[name] = (fused, engages or (lambda spec, pool_spec: True))
     else:
         _FUSED.pop(name, None)
 
@@ -77,9 +92,27 @@ def get_backend(name: str | None) -> ConvFn:
                          f"registered: {available_backends()}") from None
 
 
-def has_fused(name: str | None) -> bool:
-    """Does ``name`` register a fused conv-epilogue lowering?"""
-    return (name or DEFAULT_BACKEND) in _FUSED
+def fuses(name: str | None, spec: LayerSpec,
+          pool_spec: LayerSpec | None) -> bool:
+    """Does backend ``name`` lower conv ``spec`` (with the VALID
+    kernel == stride pool ``pool_spec``, or alone) through its fused
+    lowering?"""
+    entry = _FUSED.get(name or DEFAULT_BACKEND)
+    return entry is not None and entry[1](spec, pool_spec)
+
+
+def phase_form(name: str | None) -> bool:
+    """Does backend ``name`` fuse its chains in phase form
+    (:func:`conv_pool_phase`)?"""
+    entry = _FUSED.get(name or DEFAULT_BACKEND)
+    return entry is not None and entry[0] is conv_pool_phase
+
+
+def lane_tiled() -> bool:
+    """Does the platform keep a tensor's minor dimension in 128-lane
+    vector tiles (TPU), so that a 3- or 32-channel tensor pays for 128
+    lanes?  The same observation :func:`default_interpret` makes."""
+    return jax.default_backend() == "tpu"
 
 
 def default_interpret() -> bool:
@@ -141,7 +174,82 @@ def _conv_pallas_fused(spec: LayerSpec, pool_spec: LayerSpec | None, p: dict,
                         interpret=default_interpret())
 
 
-register_backend("xla", _conv_xla)
+#: the lane tile of a TPU vector register: a space-to-depth input of at
+#: most this many channels stays within one tile
+LANES = 128
+
+
+def phase_engages(spec: LayerSpec, pool_spec: LayerSpec | None) -> bool:
+    """The chains :func:`conv_pool_phase` takes: on a lane-tiled
+    platform, a stride-1 conv into a VALID 2x2/2 pool whose
+    space-to-depth input, ``4 * Cin`` channels, fits one lane tile —
+    where the conv's lane-sparse input and output cost more than its
+    FLOPs."""
+    return (pool_spec is not None and lane_tiled()
+            and tuple(spec.stride) == (1, 1)
+            and tuple(pool_spec.kernel) == (2, 2)
+            and tuple(pool_spec.stride) == (2, 2)
+            and tuple(pool_spec.padding) == (0, 0)
+            and 4 * spec.in_channels <= LANES)
+
+
+def phase_kernel(w: jax.Array, p: int, bh: int, bw: int) -> jax.Array:
+    """The ``(p*bh, p*bw, CI, p*p*CO)`` kernel that computes all ``p*p``
+    output phases of a stride-1 ``(KH, KW, CI, CO)`` conv at stride
+    ``p``: output channel ``(a, b, co)`` is the conv output at phase
+    ``(a, b)``, i.e. ``w`` shifted by ``(a, b)`` in a zero window.  On
+    the ``p``-space-to-depth grid it is the ``bh x bw`` block kernel
+    from ``p*p*CI`` to ``p*p*CO`` channels."""
+    kh, kw, _, _ = w.shape
+    return jnp.concatenate(
+        [jnp.pad(w, ((a, p * bh - kh - a), (b, p * bw - kw - b),
+                     (0, 0), (0, 0)))
+         for a in range(p) for b in range(p)], axis=-1)
+
+
+def conv_pool_phase(spec: LayerSpec, pool_spec: LayerSpec, p: dict,
+                    x: jax.Array, pad_w: tuple[int, int]) -> jax.Array:
+    """A stride-1 conv + bias + ``spec.act`` + VALID P x P/P max-pool
+    in space-to-depth phase form.
+
+    The P*P conv outputs of each pool window become channels: one VALID
+    stride-P conv with :func:`phase_kernel` (the ``bh x bw`` block
+    kernel of the space-to-depth grid, 2x2 for a 3x3 conv, so 16/9 of
+    the MACs) writes P*P*Cout dense channels, and the pool is the
+    elementwise max of the P*P phase groups' channel slices.  Bias and
+    activation follow the max: every activation is non-decreasing, so
+    this equals the pool of the activated conv, with only the conv's
+    f32 sums in another order.  The tile is padded (or cropped) to the
+    grid the phase kernel reads; taps past the conv's own window meet
+    zero weights.
+
+    Written as a strided conv on the tile, not as :func:`reorg` plus a
+    stride-1 block conv, and with slices, not a reshape, for the max:
+    in a v5e compile of yolov2 both of those lowered to relayouts of
+    lane-padded tensors that cost more than the conv they served."""
+    pp = pool_spec.kernel[0]
+    kh, kw = spec.kernel[1], spec.kernel[0]
+    ph = spec.padding[1]
+    h, w = x.shape[1:3]
+    ho = (h + 2 * ph - kh + 1) // pp               # pooled output grid
+    wo = (w + pad_w[0] + pad_w[1] - kw + 1) // pp
+    bh, bw = -(-(kh + pp - 1) // pp), -(-(kw + pp - 1) // pp)
+    hz, wz = pp * (ho + bh - 1), pp * (wo + bw - 1)
+    xp = jax.lax.pad(x, jnp.zeros((), x.dtype),
+                     ((0, 0, 0), (ph, hz - h - ph, 0),
+                      (pad_w[0], wz - w - pad_w[0], 0), (0, 0, 0)))
+    y = jax.lax.conv_general_dilated(
+        xp, phase_kernel(p["w"], pp, bh, bw),
+        window_strides=(pp, pp), padding="VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    co = y.shape[-1] // (pp * pp)
+    y = functools.reduce(jnp.maximum, (y[..., i * co:(i + 1) * co]
+                                       for i in range(pp * pp)))
+    return activation(y + p["b"], spec.act)
+
+
+register_backend("xla", _conv_xla, fused=conv_pool_phase,
+                 engages=phase_engages)
 register_backend("pallas", _conv_pallas, fused=_conv_pallas_fused)
 
 
@@ -157,16 +265,15 @@ def apply_conv(spec: LayerSpec, p, x: jax.Array,
     activation ``spec.act`` + optional non-overlapping max-pool) to an
     NHWC tile.
 
-    Backends with a fused lowering execute the whole chain as one
-    kernel call; others compose the exact eager sequence, so a backend
-    without fusion stays bit-identical to the oracle.  ``pool_spec``
-    must describe a VALID kernel==stride pool (the only shape
-    ``fusable_chains`` emits).
+    A chain the backend's fused lowering takes (:func:`fuses`) runs
+    as one fused call; any other composes the exact eager sequence, so
+    it stays bit-identical to the oracle.  ``pool_spec`` must describe
+    a VALID kernel==stride pool (the only shape ``fusable_chains``
+    emits).
     """
     name = backend or DEFAULT_BACKEND
-    fused = _FUSED.get(name)
-    if fused is not None:
-        return fused(spec, pool_spec, p, x, pad_w)
+    if fuses(name, spec, pool_spec):
+        return _FUSED[name][0](spec, pool_spec, p, x, pad_w)
     y = activation(get_backend(name)(spec, p, x, pad_w) + p["b"], spec.act)
     if pool_spec is not None:
         y = jax.lax.reduce_window(

@@ -29,11 +29,12 @@ import jax
 from ..obs.metrics import default_registry
 from ..pipeline.halo import (TilePlan, plan_tiles, split_inputs,
                              stitch_outputs)
-from .backends import has_fused
+from .backends import fuses, phase_form
 
 
-def fusable_chains(graph, nodes) -> dict[str, str]:
-    """conv -> pool pairs in ``nodes`` lowerable as one fused kernel.
+def fusable_chains(graph, nodes, backend: str | None = None
+                   ) -> dict[str, str]:
+    """conv -> pool pairs in ``nodes`` lowerable as one fused call.
 
     A pool is fusable into its producing conv when the chain is private
     and the pool collapses onto the conv's output grid:
@@ -49,6 +50,9 @@ def fusable_chains(graph, nodes) -> dict[str, str]:
     these conditions also pin the tile geometry: the conv tile is
     exactly the pool's input and starts on the pool grid, which
     ``run_segment`` re-checks per tile before fusing.
+
+    With ``backend`` given, only the chains that backend's fused
+    lowering takes (``backends.fuses``) are kept.
     """
     nodes = frozenset(nodes)
     sinks = set(graph.sinks(nodes))
@@ -67,6 +71,9 @@ def fusable_chains(graph, nodes) -> dict[str, str]:
         if graph.layers[conv].kind != "conv" or conv in sinks:
             continue
         if [s for s in graph.succs[conv] if s in nodes] != [n]:
+            continue
+        if backend is not None \
+                and not fuses(backend, graph.layers[conv], spec):
             continue
         chains[conv] = n
     return chains
@@ -106,12 +113,14 @@ class CompiledStage:
         self.needs = list(needs)
         self.sinks = list(sinks)
         self.backend = backend
-        # conv->pool chains lowered as one fused kernel call; only for
-        # backends with a fused lowering (xla keeps the composed-op
-        # sequence and with it bit-equality vs the eager oracle)
+        # conv->pool chains lowered as one fused call: only those the
+        # backend's fused lowering takes (pallas: all; xla: lane-sparse
+        # chains on a TPU, in phase form).  Every other chain keeps the
+        # composed-op sequence and with it bit-equality vs the eager
+        # oracle
         self.fuse = bool(fuse)
-        self.fusion = fusable_chains(model.graph, self.nodes) \
-            if self.fuse and has_fused(backend) else {}
+        self.fusion = fusable_chains(model.graph, self.nodes, backend) \
+            if self.fuse else {}
         # XLA on CPU cannot alias donated buffers; donation there only
         # produces warnings, so honor the flag on accelerators only
         self.donate = bool(donate) and jax.default_backend() != "cpu"
@@ -126,6 +135,11 @@ class CompiledStage:
             spec = model.graph.layers[n]
             act = spec.act if spec.kind == "conv" else "none"
             reg.counter("stage.layers", kind=spec.kind, act=act).inc()
+        # chains built in space-to-depth phase form, by input channels
+        if phase_form(backend):
+            for conv in self.fusion:
+                reg.counter("stage.phase_pools",
+                            cin=model.graph.layers[conv].in_channels).inc()
 
     # traced bodies ------------------------------------------------------
 

@@ -16,7 +16,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.graph import LayerSpec
 from repro.exec.autotune import conv_shapes
+from repro.exec.backends import conv_pool_phase
 from repro.kernels.conv2d.conv2d import conv2d_fused
 from repro.models.cnn import zoo
 from repro.pipeline.stage import StageExecutor
@@ -123,3 +125,19 @@ def test_vgg16_whole_model_xla_stage_compiles_for_v5e(one_chip):
     compiled = _whole_model_stage(m, one_chip)
     (sink,) = m.graph.sinks()
     assert compiled.out_info[sink].shape == (1, 1, 1, 1000)
+
+
+@pytest.mark.parametrize("cin,cout,hw", [(3, 32, 608), (32, 64, 304)])
+def test_yolov2_phase_chains_compile_for_v5e(cin, cout, hw, one_chip):
+    """yolov2's two lane-sparse conv -> 2x2/2 pool chains at 608, in the
+    space-to-depth phase form the xla backend gives them on a TPU."""
+    spec = LayerSpec("c", "conv", (3, 3), (1, 1), (1, 1), cin, cout,
+                     act="leaky")
+    pool = LayerSpec("p", "pool", (2, 2), (2, 2), (0, 0), cout, cout)
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    p = {"w": sds((3, 3, cin, cout)), "b": sds((cout,))}
+    compiled = jax.jit(lambda p, x: conv_pool_phase(
+        spec, pool, p, x, (1, 1))).lower(p, sds((1, hw, hw, cin))).compile()
+    assert compiled.out_info.shape == (1, hw // 2, hw // 2, cout)
